@@ -75,7 +75,6 @@ def _preseed_ns_of(key):
 #: runtime must land on a pre-seeded literal
 DYNAMIC_KEY_PATTERNS = (
     'fallback.escalated.w*',        # tier ladder: one key per width
-    'fallback.pallas_*_latch',      # per-kernel pallas latch-off
     'resilience.fault_injected.*',  # per-site subkeys (base is seeded)
     '*.latch_flip_ignored',         # resident./mesh. via namespace map
 )

@@ -101,8 +101,8 @@ class TransientFault(InjectedFault):
 
 
 class PermanentFault(InjectedFault):
-    """A fault that models a deterministic failure (poison doc, wedged
-    kernel): retries never clear it; isolation/quarantine must."""
+    """A fault that models a deterministic failure (a poison doc):
+    retries never clear it; isolation/quarantine must."""
 
     kind = 'permanent'
 

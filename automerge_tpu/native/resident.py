@@ -59,6 +59,11 @@ def _sp_min():
     return env_int('AMTPU_MESH_SP_MIN', SP_CROSSOVER_ELEMS)
 
 
+#: `_sp_sharding`'s default cap: read ``AMTPU_MESH`` (a pool built
+#: from explicit mesh axes passes its own)
+FROM_ENV = object()
+
+
 def _sp_device_cap():
     """How many devices the sp axis may claim: None = every local
     device (legacy auto policy, no AMTPU_MESH set), 0 = fenced off
@@ -136,7 +141,7 @@ def _sp_mesh(n_cap=None):
     return Mesh(np.array(devices[:n]), ('sp',))
 
 
-def _sp_sharding(capacity=None, count_fenced=False):
+def _sp_sharding(capacity=None, count_fenced=False, cap=FROM_ENV):
     """Element-axis sharding for a resident column of `capacity` rows,
     or None when sharding is unavailable/indivisible -- or FENCED (the
     caller then keeps the column replicated and uses the unsharded
@@ -147,8 +152,10 @@ def _sp_sharding(capacity=None, count_fenced=False):
     sharding as ``mesh.sp_fenced`` -- passed ONLY by the dispatch
     decision site, so fenced counts one per dispatch exactly like its
     ``mesh.sp_engaged`` counterpart (placement/sync callers would
-    otherwise inflate it 3-4x)."""
-    cap = _sp_device_cap()
+    otherwise inflate it 3-4x).  `cap` is `_sp_device_cap`'s value
+    for a pool whose mesh axes were given, not read."""
+    if cap is FROM_ENV:
+        cap = _sp_device_cap()
     if cap == 0:
         return None
     mesh = _sp_mesh(cap)
@@ -228,6 +235,7 @@ def _bucket_pow2(n, floor=16):
 
 class ResidentCache:
     def __init__(self):
+        self.sp_cap = FROM_ENV   # the sp devices its arenas may shard over
         self.entries = {}        # (doc_id bytes, obj_sid) -> ResidentArena
         self.actor_order = []    # sorted actor strings (bytes)
         self.sid_str = {}        # sid -> actor string
@@ -315,7 +323,7 @@ class ResidentCache:
             import jax
             entry = ResidentArena(capacity)
             pad = capacity - n_now
-            sharding = _sp_sharding(capacity)
+            sharding = _sp_sharding(capacity, cap=self.sp_cap)
 
             def up(a, dtype, fill):
                 arr = jnp.asarray(np.pad(
@@ -335,7 +343,7 @@ class ResidentCache:
             kp = _bucket_pow2(k)
             idx = np.full(kp, capacity, np.int32)   # capacity = dropped
             idx[:k] = np.arange(lo, n_now, dtype=np.int32)
-            scatter = _jit_scatter(_sp_sharding(capacity))
+            scatter = _jit_scatter(_sp_sharding(capacity, cap=self.sp_cap))
 
             def pad(a, dtype):
                 out = np.zeros(kp, dtype)
@@ -370,6 +378,7 @@ class ResidentCache:
             vals = np.zeros(kp, np.float32)
             vals[:touched_eidx.size] = vis[touched_eidx]
             entry.ev = _jit_scatter(
-                _sp_sharding(entry.capacity))(entry.ev, idx, vals)
+                _sp_sharding(entry.capacity, cap=self.sp_cap))(
+                    entry.ev, idx, vals)
         entry.n = n_now
         entry.dirty = False

@@ -1,42 +1,19 @@
-"""Shared Pallas dispatch gate for the TPU kernel twins.
-
-One definition of "should a Pallas formulation run here": on-TPU check
-cached once per process, `AMTPU_NO_PALLAS` kill switch re-read per call.
-Per-kernel latches (e.g. lowering failures) layer on top in each
-kernel's module.
-"""
+"""Shared Pallas dispatch gate for the TPU kernel twins: Pallas runs on
+a TPU backend unless the `AMTPU_NO_PALLAS` kill switch (re-read per
+call) is set."""
 
 import functools
-import sys
 
 import jax
 from ..utils.common import env_bool
 
 
-def _on_tpu():
-    try:
-        return jax.devices()[0].platform == 'tpu'
-    except Exception:
-        return False
-
-
 @functools.lru_cache(maxsize=1)
 def on_tpu_cached():
-    return _on_tpu()
+    return jax.default_backend() == 'tpu'
 
 
 def pallas_enabled():
     if env_bool('AMTPU_NO_PALLAS', False):
         return False
     return on_tpu_cached()
-
-
-def report_latch(kernel, exc):
-    """A Pallas kernel failed to lower/run and latched itself off: make
-    that observable -- always-on metric (bench JSON surfaces it), trace
-    counter, and one stderr line with the lost exception text."""
-    from .. import trace
-    trace.metric('fallback.pallas_%s_latch' % kernel)
-    trace.count('pallas.%s_latch' % kernel)
-    print('amtpu: pallas %s kernel latched off: %r' % (kernel, exc),
-          file=sys.stderr)

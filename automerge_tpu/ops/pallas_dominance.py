@@ -21,8 +21,8 @@ VMEM-blocked compute:
       vis  += sum_k delta_k * onehot(elem_k)     (VPU, [K, L])
 
 Eligibility: L and K multiples of 128/lane tiling are padded by the
-caller's shape buckets; the dispatcher `dominance_grouped_auto` falls back
-to the XLA kernel off-TPU or for tiny shapes.
+caller's shape buckets; the dispatcher `dominance_grouped_auto` routes
+to the XLA kernel off-TPU or for shapes outside the tiling.
 """
 
 import functools
@@ -32,7 +32,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import trace
 from . import list_rank
+from .pallas_common import pallas_enabled
 
 
 # objects processed per grid program (the sublane tiling minimum)
@@ -109,11 +111,6 @@ def dominance_grouped_pallas(vis0, elem_rank, op_elem, op_rank, op_delta,
       op_delta.astype(jnp.int32), op_valid.astype(jnp.int32))
 
 
-def _use_pallas():
-    from .pallas_common import pallas_enabled
-    return pallas_enabled()
-
-
 def dominance_grouped_auto(vis0, elem_rank, op_elem, op_rank, op_delta,
                            op_valid, chunk=64):
     """Pallas on TPU when the lane tiling fits; XLA kernel otherwise.
@@ -127,10 +124,12 @@ def dominance_grouped_auto(vis0, elem_rank, op_elem, op_rank, op_delta,
     # timeline blocks must fit with headroom.
     PK = 128
     vmem_bytes = 2 * _ROWS * PK * L * 4 + 6 * _ROWS * T * 4
-    if (_use_pallas() and L % 128 == 0 and T % PK == 0
+    if (pallas_enabled() and L % 128 == 0 and T % PK == 0
             and W % _ROWS == 0 and vmem_bytes <= 10 * 2 ** 20):
+        trace.count('ops.dominance.pallas')
         return dominance_grouped_pallas(
             vis0, elem_rank, op_elem, op_rank, op_delta, op_valid,
             chunk=PK)
+    trace.count('ops.dominance.xla')
     return list_rank.dominance_grouped(
         vis0, elem_rank, op_elem, op_rank, op_delta, op_valid, chunk=chunk)

@@ -1,10 +1,9 @@
 """Poison-batch isolation + graceful degradation (docs/RESILIENCE.md).
 
-One malformed change, one transient XLA/device error, or one wedged
-kernel used to take down an entire multi-thousand-doc batch.  This
-module turns a device- or native-path failure inside
-``NativeDocPool.apply_batch`` / ``ShardedNativePool`` into the smallest
-possible blast radius:
+One malformed change or one failing native call used to take down an
+entire multi-thousand-doc batch.  This module turns such a failure
+inside ``NativeDocPool.apply_batch`` / ``ShardedNativePool`` into the
+smallest possible blast radius:
 
   1. **retry** -- transient failures (``faults.is_transient``) get
      bounded retries with exponential backoff
@@ -44,6 +43,7 @@ post-rollback).
 import time
 
 import msgpack
+from jax.errors import JaxRuntimeError
 
 from . import faults, telemetry
 from .errors import AutomergeError
@@ -65,8 +65,8 @@ def _backoff_base_s():
     return env_float('AMTPU_RETRY_BACKOFF_S', 0.05)
 
 
-#: exponential backoff ceiling -- a wedged device should not turn one
-#: batch into a minutes-long retry stall
+#: exponential backoff ceiling -- a persistent fault should not turn
+#: one batch into a minutes-long retry stall
 _BACKOFF_CAP_S = 1.0
 
 
@@ -78,11 +78,12 @@ def should_isolate(exc):
     """Whether the resilience machinery may handle ``exc`` at all.
 
     Injected faults always qualify.  Real-world infrastructure failures
-    (RuntimeError covers XlaRuntimeError, OSError covers device/file
-    descriptors, MemoryError/SystemError cover allocator/interpreter
-    trouble) qualify unless the batch is state-suspect.  Protocol
-    validation errors never do -- the whole-batch raise IS their
-    contract.
+    (RuntimeError, OSError, MemoryError/SystemError for allocator or
+    interpreter trouble) qualify unless the batch is state-suspect.  A
+    failing device does not (`JaxRuntimeError`): it is no one doc's
+    poison, and retrying, quarantining or degrading to the host path
+    around it would hide it -- the batch raises.  Protocol validation
+    errors never do either -- the whole-batch raise IS their contract.
     """
     if not enabled():
         return False
@@ -90,7 +91,8 @@ def should_isolate(exc):
         return False
     if isinstance(exc, faults.InjectedFault):
         return True
-    if isinstance(exc, (AutomergeError, TypeError, KeyError)):
+    if isinstance(exc, (AutomergeError, TypeError, KeyError,
+                        JaxRuntimeError)):
         return False
     return isinstance(exc, (RuntimeError, OSError, MemoryError,
                             SystemError))
@@ -252,7 +254,8 @@ def _apply_group(pool, keyed, doc_list, parts, pending_exc=None):
 def _apply_degraded(pool, key, changes):
     """Applies one poisoned doc on the FULL HOST path: the C++ pool
     resolves registers and list indexes itself with zero device
-    dispatches, dodging whatever wedged the kernel path.  Returns the
+    dispatches.  A device failure never gets here (`should_isolate`
+    refuses it).  Returns the
     raw result bytes.  Counted as ``resilience.degraded`` -- NOT
     ``fallback.oracle``, which gates the healthy kernel path's
     escalation ladder."""

@@ -127,11 +127,12 @@ class ReplicaSupervisor(object):
         it to the ring, and registers its durable store with the
         failover executor.  Returns the member id."""
         member = self._member_name(base, gen)
+        env = dict(os.environ)
+        env.update(self.spawn_env)
+        self._refuse_chip_contention(member, env)
         sock_path = os.path.join(self.base_dir, member + '.sock')
         store_dir = os.path.join(self.base_dir, 'store-' + member)
         os.makedirs(store_dir, exist_ok=True)
-        env = dict(os.environ)
-        env.update(self.spawn_env)
         env.update({'AMTPU_REPLICA_ID': member,
                     'AMTPU_STORAGE_DIR': store_dir,
                     'AMTPU_STORAGE_DURABLE': '1',
@@ -165,6 +166,22 @@ class ReplicaSupervisor(object):
             telemetry.recorder.record('fleet.rejoin', doc=member,
                                       n=gen)
         return member
+
+    def _refuse_chip_contention(self, member, env):
+        """A replica off the CPU takes every chip of the host when its
+        JAX starts, so a second one could get none: refuse it while
+        another lives.  Replicas each on a chip of their own are
+        ROADMAP B1."""
+        if env.get('JAX_PLATFORMS') == 'cpu':
+            return
+        with self._lock:
+            live = sorted(m for m, p in self._procs.items()
+                          if p.poll() is None)
+        if live:
+            raise RuntimeError(
+                'replica %r would compete for the chips that replica %r '
+                'holds: one accelerator replica per host (run the fleet '
+                'with JAX_PLATFORMS=cpu for more)' % (member, live[0]))
 
     def spawn_fleet(self, n, prefix='r'):
         return [self.spawn('%s%d' % (prefix, i)) for i in range(n)]

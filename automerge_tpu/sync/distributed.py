@@ -422,13 +422,9 @@ class DistributedReplicaSet:
 
 def _worker(pid, n_processes, coord_port, mesh_port_base):
     os.environ['JAX_PLATFORMS'] = 'cpu'
-    from ..utils.jaxenv import enable_cpu_collectives, pin_cpu
-    pin_cpu(force=True)
+    from ..utils.jaxenv import enable_cpu_collectives
     import jax
-    # CPU multi-process collectives need the Gloo backend opt-in on jax
-    # versions that gate it (without it every process_allgather dies
-    # with "Multiprocess computations aren't implemented on the CPU
-    # backend")
+    jax.config.update('jax_platforms', 'cpu')
     enable_cpu_collectives()
     jax.distributed.initialize(
         coordinator_address='127.0.0.1:%d' % coord_port,

@@ -168,8 +168,8 @@ KNOWN_PIPELINE_KEYS = ('batches', 'waves', 'serial_replay')
 # collective_wait_s       time a collector blocked on a chip whose
 #                           device outputs had not resolved (nothing
 #                           else was ready)
-# device_shortfall        mesh pools built with fewer devices than
-#                           dp x sp (round-robin placement degradation)
+# device_shortfall        mesh pools refused: dp x sp exceeds the
+#                           devices that exist (the pool raises)
 # sp_fenced / sp_engaged  resident dispatches the sp-axis crossover
 #                           fence kept single-chip vs routed sharded
 # latch_flip_ignored      AMTPU_MESH* env flips after the first batch

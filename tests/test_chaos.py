@@ -201,6 +201,35 @@ class TestFaultMatrix:
             assert not snap.get('resilience.quarantined'), snap
         assert not snap.get('fallback.oracle'), snap
 
+    def test_device_error_raises_untouched(self, monkeypatch):
+        """A failing device is no doc's poison: a JaxRuntimeError from
+        the kernel fails the batch, and nothing is retried, quarantined
+        or degraded around it (AMTPU_DEGRADE=1 on, to show it is not
+        taken)."""
+        from jax.errors import JaxRuntimeError
+        from automerge_tpu.ops import pallas_registers
+        calls = []
+
+        def device_fault(*args, **kw):
+            calls.append(1)
+            raise JaxRuntimeError('INTERNAL: the device halted')
+        monkeypatch.setattr(pallas_registers, 'resolve_registers_auto',
+                            device_fault)
+        monkeypatch.setenv('AMTPU_HOST_FULL', '0')
+        monkeypatch.setenv('AMTPU_DEGRADE', '1')
+        # two writers per key: the batch needs the register kernel
+        docs = {('d%d' % i): [
+            {'actor': 'a%d' % a, 'seq': 1, 'deps': {},
+             'ops': [{'action': 'set', 'obj': ROOT_ID, 'key': 'k',
+                      'value': a}]} for a in range(2)] for i in range(4)}
+        with pytest.raises(JaxRuntimeError):
+            NativeDocPool().apply_batch(docs)
+        snap = telemetry.metrics_snapshot()
+        assert calls == [1], calls
+        for k in ('resilience.retry.attempts', 'resilience.quarantined',
+                  'resilience.degraded', 'fallback.oracle'):
+            assert not snap.get(k), (k, snap)
+
     def test_checkpoint_load_fault_surfaces_and_clears(self, exec_mode):
         """checkpoint.load faults surface to the caller (the WAL replay
         driver owns the retry policy there); a retry after the fault

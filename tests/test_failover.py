@@ -486,6 +486,27 @@ def test_supervisor_respawns_then_quarantines(tmp_path, monkeypatch):
     assert flat.get('failover.quarantined') == 1
 
 
+def test_supervisor_refuses_second_chip_replica(tmp_path, monkeypatch):
+    """Off the CPU a replica holds the host's chips: a second live one
+    is refused before anything is spawned; CPU fleets are not limited."""
+    import subprocess
+    from automerge_tpu.router.supervisor import ReplicaSupervisor
+
+    class _Live(object):
+        def poll(self):
+            return None
+
+    def no_popen(*a, **k):
+        raise AssertionError('spawned a replica that needs a held chip')
+    monkeypatch.setattr(subprocess, 'Popen', no_popen)
+    sup = ReplicaSupervisor(object(), str(tmp_path),
+                            spawn_env={'JAX_PLATFORMS': ''})
+    sup._procs['r0'] = _Live()
+    with pytest.raises(RuntimeError, match='compete for the chips'):
+        sup.spawn('r1')
+    sup._refuse_chip_contention('r1', {'JAX_PLATFORMS': 'cpu'})
+
+
 # ---------------------------------------------------------------------------
 # write-through checkpointing (the durability the restore rests on)
 # ---------------------------------------------------------------------------

@@ -80,6 +80,18 @@ def test_mesh_pool_places_chips_on_distinct_devices():
     assert len(set(devs)) == 4, devs
 
 
+def test_mesh_pool_device_shortfall_raises():
+    """A mesh that needs more devices than exist is refused: several
+    chips' work never shares one device."""
+    import jax
+    n = len(jax.devices())
+    telemetry.metrics_reset()
+    pool = MeshDocPool(dp=n + 1)
+    with pytest.raises(RuntimeError, match='only %d exist' % n):
+        pool.pools
+    assert telemetry.metrics_snapshot().get('mesh.device_shortfall') == 1
+
+
 def test_mesh_pool_serves_kernel_path_with_zero_oracle(
         workload_and_reference):
     _docs, payload, _want = workload_and_reference
@@ -167,12 +179,57 @@ def test_sp_fence_routing_policy(monkeypatch):
     assert resident._sp_sharding(crossover) is None
 
 
+SP_FROM_AXES = r"""
+import os, sys
+sys.path.insert(0, REPO_PATH)
+import jax; jax.config.update('jax_platforms', 'cpu')
+from automerge_tpu import telemetry, backend as Backend
+from automerge_tpu.native import make_pool
+from automerge_tpu.native.mesh_pool import MeshDocPool
+ROOT = '00000000-0000-0000-0000-000000000000'
+telemetry.enable()
+ops, prev = [{'action': 'makeText', 'obj': 't'},
+             {'action': 'link', 'obj': ROOT, 'key': 'text', 'value': 't'}], '_head'
+for e in range(1, 301):
+    ops.append({'action': 'ins', 'obj': 't', 'key': prev, 'elem': e})
+    ops.append({'action': 'set', 'obj': 't', 'key': 'a0:%d' % e, 'value': 'x'})
+    prev = 'a0:%d' % e
+chs = [{'actor': 'a0', 'seq': 1, 'deps': {}, 'ops': ops}]
+st, _ = Backend.apply_changes(Backend.init(), chs)
+assert make_pool().dp == 4
+engaged = []
+for sp in (1, 2):
+    pool = MeshDocPool(dp=1, sp=sp)
+    before = telemetry.metrics_snapshot().get('mesh.sp_engaged', 0)
+    pool.apply_changes('doc', chs)
+    engaged.append(telemetry.metrics_snapshot().get('mesh.sp_engaged', 0)
+                   > before)
+    assert pool.get_patch('doc') == Backend.get_patch(st), sp
+assert engaged == [False, True], engaged
+assert not telemetry.metrics_snapshot().get('mesh.latch_flip_ignored')
+print('SP-FROM-AXES-OK')
+""".replace('REPO_PATH', repr(REPO))
+
+
+def test_sp_follows_the_pools_own_axes():
+    """MeshDocPool(dp=1, sp=2) shards a long arena over its sp devices
+    while AMTPU_MESH names another topology (dp=4, never flipped), and
+    sp=1 does not: the axes given are the axes used."""
+    env = dict(os.environ, JAX_PLATFORMS='cpu', AMTPU_MESH='4',
+               AMTPU_RESIDENT='1', AMTPU_RESIDENT_MIN='16',
+               AMTPU_MESH_SP_MIN='16',
+               XLA_FLAGS='--xla_force_host_platform_device_count=8')
+    out = subprocess.run([sys.executable, '-c', SP_FROM_AXES], env=env,
+                         cwd=REPO, capture_output=True, text=True,
+                         timeout=300)
+    assert out.returncode == 0, out.stdout + out.stderr
+    assert 'SP-FROM-AXES-OK' in out.stdout
+
+
 MESH_LATCH = r"""
 import os, sys, warnings
 sys.path.insert(0, REPO_PATH)
 os.environ['JAX_PLATFORMS'] = 'cpu'
-from automerge_tpu.utils.jaxenv import pin_cpu
-pin_cpu(force=True)
 from automerge_tpu import telemetry
 from automerge_tpu.native import NativeDocPool
 ROOT = '00000000-0000-0000-0000-000000000000'

@@ -740,6 +740,79 @@ class TestPallasRegisters:
                   'overflow', 'packed'):
             assert (np.asarray(got[k]) == np.asarray(want[k])).all(), k
 
+    def test_kernel_error_raises_every_time(self, monkeypatch):
+        """Where the dispatcher picks Pallas, a kernel failure raises --
+        here Mosaic refusing a CPU backend -- and nothing latches the
+        kernel off: the next call picks Pallas again."""
+        from automerge_tpu import telemetry
+        from automerge_tpu.ops import pallas_registers
+        monkeypatch.setattr(pallas_registers, 'pallas_enabled',
+                            lambda: True)
+        (group, time, actor, seq, is_del, sort_idx,
+         clock_table, idx) = self._random_case(3)
+        telemetry.metrics_reset()
+        for _ in range(2):
+            with pytest.raises(Exception):
+                pallas_registers.resolve_registers_auto(
+                    group, time, actor, seq, is_del, np.ones_like(is_del),
+                    sort_idx, clock_table, idx, window=4)
+        assert not [k for k in telemetry.metrics_snapshot()
+                    if 'pallas' in k]
+
+    def test_gate_sends_wide_actor_sets_to_the_twin(self, monkeypatch):
+        """Past the VMEM gate (256 actors at W=8 overflows Mosaic's
+        scoped VMEM on a v5e) the dispatcher picks the XLA twin even
+        where Pallas is on; at 16 actors it picks the kernel, which
+        cannot run here and raises."""
+        from automerge_tpu.ops import pallas_registers
+        monkeypatch.setattr(pallas_registers, 'pallas_enabled',
+                            lambda: True)
+        assert pallas_registers.widest_actors(8) < 256
+        for A in (16, 256):
+            (group, time, actor, seq, is_del, sort_idx,
+             clock_table, idx) = self._random_case(4, A=A, window=8)
+            args = (group, time, actor, seq, is_del, np.ones_like(is_del),
+                    sort_idx, clock_table, idx)
+            if A == 16:
+                with pytest.raises(Exception):
+                    pallas_registers.resolve_registers_auto(*args, window=8)
+            else:
+                got = pallas_registers.resolve_registers_auto(*args,
+                                                              window=8)
+                assert np.asarray(got['packed']).shape == (256,)
+
+    def test_pool_window_batch_takes_the_kernel(self, monkeypatch):
+        """A registers-only batch with no key wider than the window goes
+        from make_pool() to the Pallas kernel (interpreted here), with
+        oracle-identical patches: the route chip_smoke.py's window phase
+        drives on the chip."""
+        from automerge_tpu import backend as Backend
+        from automerge_tpu.native import make_pool
+        from automerge_tpu.ops import pallas_registers
+        kernel = pallas_registers.resolve_registers_pallas
+        windows = []
+
+        def interpreted(*args, **kw):
+            windows.append(kw['window'])
+            return kernel(*args, interpret=True, **kw)
+        monkeypatch.setattr(pallas_registers, 'pallas_enabled',
+                            lambda: True)
+        monkeypatch.setattr(pallas_registers, 'resolve_registers_pallas',
+                            interpreted)
+        monkeypatch.setenv('AMTPU_HOST_FULL', '0')
+        rng = random.Random(5)
+        batch = {'m%d' % d: [
+            {'actor': 'a%d' % a, 'seq': 1, 'deps': {}, 'ops': [
+                {'action': 'set', 'obj': ROOT_ID, 'key': 'k%d' % k,
+                 'value': a} for k in rng.sample(range(8), 4)]}
+            for a in range(8)] for d in range(8)}
+        pool = make_pool()
+        pool.apply_batch(batch)
+        assert windows and max(windows) <= 8
+        for d, chs in batch.items():
+            st, _ = Backend.apply_changes(Backend.init(), chs)
+            assert pool.get_patch(d) == Backend.get_patch(st), d
+
     def test_auto_dispatch_fallback(self):
         # off-TPU the dispatcher must route to the XLA kernel
         from automerge_tpu.ops.pallas_registers import \

@@ -35,9 +35,6 @@ os.environ['AMTPU_HOST_REG'] = '0'
 os.environ.setdefault('AMTPU_BENCH_DOCS', '48')
 os.environ.setdefault('AMTPU_BENCH_ACTORS', '4')
 
-from automerge_tpu.utils.jaxenv import pin_cpu  # noqa: E402
-pin_cpu()
-
 import msgpack  # noqa: E402
 
 from automerge_tpu import faults, resilience, telemetry  # noqa: E402

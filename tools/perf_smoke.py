@@ -117,8 +117,6 @@ def resident_hit_gate():
     cache were wiped between apply calls -- the exact regression this
     gate exists to catch."""
     os.environ['AMTPU_PIPELINE_DEPTH'] = '1'
-    from automerge_tpu.utils.jaxenv import pin_cpu
-    pin_cpu()
     import random
 
     import msgpack

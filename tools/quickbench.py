@@ -30,9 +30,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from automerge_tpu.utils.jaxenv import pin_cpu  # noqa: E402
-pin_cpu()
-
 import msgpack  # noqa: E402
 
 from automerge_tpu import telemetry  # noqa: E402

@@ -37,9 +37,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 os.environ.setdefault('AMTPU_BENCH_DOCS', '256')
 os.environ.setdefault('AMTPU_BENCH_ORACLE_DOCS', '1')
 
-from automerge_tpu.utils.jaxenv import pin_cpu  # noqa: E402
-pin_cpu()
-
 import msgpack  # noqa: E402
 
 from automerge_tpu import telemetry, trace  # noqa: E402
