@@ -1,0 +1,12 @@
+"""Dispatch: `device.collect` seconds (the host blocked on device
+outputs) in the window per million ops it completed (program spans,
+traced run)."""
+
+
+def read(ctx):
+    spans = ctx['program']['spans']
+    names = ('device.collect',)
+    if not any(n in spans for n in names) or not ctx['client']['ops_done']:
+        return None
+    s = sum(spans[n]['s'] for n in names if n in spans)
+    return s / (ctx['client']['ops_done'] / 1e6)
