@@ -25,11 +25,11 @@ bench-all: native
 
 # All five configs on the accelerator: platform default (= kernel on
 # TPU) + host sibling embedded per line, plus the resident-arena lines
-# for the long-list shapes, with AMTPU_DEVTIME device busy fractions in
-# every block.  bench.py refuses to run without an accelerator.  The
-# parent stays off JAX; each config runs in a child that holds the chip.
+# for the long-list shapes.  bench.py refuses to run without an
+# accelerator.  The parent stays off JAX; each config runs in a child
+# that holds the chip.
 bench-tpu: native
-	AMTPU_DEVTIME=1 python bench.py --all --out BENCH_TPU.json
+	python bench.py --all --out BENCH_TPU.json
 
 # The pre-commit gate: native build + full test suite + a bench smoke
 # covering BOTH execution modes (the default line embeds the

@@ -77,6 +77,7 @@ DYNAMIC_KEY_PATTERNS = (
     'fallback.escalated.w*',        # tier ladder: one key per width
     'resilience.fault_injected.*',  # per-site subkeys (base is seeded)
     '*.latch_flip_ignored',         # resident./mesh. via namespace map
+    'jit.compiles.*',               # one key per compiled function
 )
 
 #: counter namespaces whose doc glossary rows are checked for deadness
@@ -84,11 +85,7 @@ DYNAMIC_KEY_PATTERNS = (
 DOC_NAMESPACES = tuple(sorted({ns.split('.')[0]
                                for ns in PRESEED_BLOCKS})) + (
     'sched', 'sidecar', 'device', 'host', 'hostfull', 'hostreg',
-    'sanitize', 'pallas', 'ops')
-
-#: flat keys that feed derived exposition families instead of a
-#: glossary row of their own (documented as amtpu_device_*_total)
-UNDOCUMENTED_OK = {'device.dispatch_sync_s', 'device.dispatches'}
+    'sanitize', 'pallas', 'ops', 'transfer', 'jit', 'gateway', 'pool')
 
 _TOKEN_RE = re.compile(r'`([A-Za-z0-9_./*%\[\]]+)`')
 _KEY_RE = re.compile(r'^[a-z][a-z0-9_]*(\.[a-zA-Z0-9_.*]+)+$')
@@ -272,8 +269,7 @@ def check(sources, ctx):
         # 2. documented somewhere
         if key not in doc_keys and _canonical(key) not in doc_canon \
                 and not any(g.match(key)
-                            for g in doc_globs_member.values()) \
-                and key not in UNDOCUMENTED_OK:
+                            for g in doc_globs_member.values()):
             findings.append(Finding(
                 CHECKER, 'undocumented-key', path, line,
                 '%s has no glossary row in docs/OBSERVABILITY.md or '

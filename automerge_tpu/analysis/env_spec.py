@@ -52,7 +52,6 @@ ENV_FLAGS = (
     EnvFlag('AMTPU_EXEMPLAR_MIN_S', 'float', 0.05, False,
             'telemetry/attribution.py (min interval between exemplar '
             'emissions; bounds the tail sampler under error storms)'),
-    EnvFlag('AMTPU_DEVTIME', 'bool', False, False, 'telemetry/__init__.py'),
     EnvFlag('AMTPU_DEGRADED_WINDOW_S', 'float', 300.0, False,
             'telemetry/__init__.py'),
     EnvFlag('AMTPU_SIDECAR_RESTARTS', 'int', 0, False,

@@ -823,22 +823,27 @@ def _apply_batch_dicts(pool, changes_by_doc):
     pool's RESILIENT wire path (pool is any object with
     apply_batch_bytes_resilient) -- a device/native-path failure is
     retried, bisected, and at worst quarantined per doc instead of
-    failing every doc in the batch (automerge_tpu.resilience)."""
-    keyed = {NativeDocPool._doc_key(d): chs
-             for d, chs in changes_by_doc.items()}
-    payload = msgpack.packb(keyed, use_bin_type=True)
-    out = msgpack.unpackb(pool.apply_batch_bytes_resilient(payload),
-                          raw=False, strict_map_key=False)
-    # the op counter lives here because this is where changes exist as
-    # decoded dicts (the bytes path can't count ops without paying a
-    # decode it otherwise avoids; docs it counts itself from the map
-    # header), and AFTER the apply so a failed batch doesn't inflate it;
-    # counts submitted ops of committed batches -- duplicates/queued
-    # changes included (the engine path counts exact causally-applied
-    # ops)
-    telemetry.OPS.inc(sum(len(c.get('ops', ()))
-                          for chs in changes_by_doc.values() for c in chs))
-    return {d: out[NativeDocPool._doc_key(d)] for d in changes_by_doc}
+    failing every doc in the batch (automerge_tpu.resilience).  The
+    dict <-> msgpack work on either side of the pool call is the
+    `pool.repack` span."""
+    with telemetry.span('pool.repack'):
+        keyed = {NativeDocPool._doc_key(d): chs
+                 for d, chs in changes_by_doc.items()}
+        payload = msgpack.packb(keyed, use_bin_type=True)
+    raw = pool.apply_batch_bytes_resilient(payload)
+    with telemetry.span('pool.repack'):
+        out = msgpack.unpackb(raw, raw=False, strict_map_key=False)
+        # the op counter lives here because this is where changes exist
+        # as decoded dicts (the bytes path can't count ops without
+        # paying a decode it otherwise avoids; docs it counts itself
+        # from the map header), and AFTER the apply so a failed batch
+        # doesn't inflate it; counts submitted ops of committed batches
+        # -- duplicates/queued changes included (the engine path counts
+        # exact causally-applied ops)
+        telemetry.OPS.inc(sum(len(c.get('ops', ()))
+                              for chs in changes_by_doc.values()
+                              for c in chs))
+        return {d: out[NativeDocPool._doc_key(d)] for d in changes_by_doc}
 
 
 def _raise_if_quarantined(doc_id, result):
@@ -880,14 +885,6 @@ def _pipeline_min_docs():
     per-wave fixed cost (split pass, extra dispatch, jit shape) beats
     the overlap.  AMTPU_PIPELINE_MIN_DOCS overrides (default 64)."""
     return env_int('AMTPU_PIPELINE_MIN_DOCS', 64)
-
-
-def _devtime_on():
-    """AMTPU_DEVTIME=1 turns on synchronous per-dispatch device timing
-    (checked per call, not latched -- bench.py flips it for one pass).
-    Single definition in telemetry so the engine and native paths can't
-    drift."""
-    return telemetry.devtime_on()
 
 
 def _host_dom_on():
@@ -1443,8 +1440,6 @@ class NativeDocPool:
                 self._resclk.drop_if_disabled(L, self._pool)
             if faults.ARMED:
                 faults.fire('device.dispatch', ctx['fault_docs'])
-            devtime = _devtime_on()
-            t0 = time.perf_counter() if devtime else 0.0
             if fused_ok:
                 with trace.span('device.dispatch'):
                     self._dispatch_fused(L, ctx, Tp, Ap, CTp, Lp, max_obj,
@@ -1468,19 +1463,6 @@ class NativeDocPool:
                     if register_ops.escalation_enabled():
                         ctx['esc'] = self._escalation_dispatch(
                             L, ctx, hovf.astype(bool))
-            if devtime:
-                # AMTPU_DEVTIME=1: block on the dispatched outputs and
-                # record the synchronous dispatch+compute time.  This
-                # serializes the shard pipeline, so bench.py measures it
-                # in a dedicated extra pass, never in the timed runs.
-                outs = [v for v in (ctx.get('combo'), ctx.get('reg_out'),
-                                    ctx.get('rank')) if v is not None]
-                if outs:                 # Tp == 0 batches dispatch nothing
-                    import jax
-                    jax.block_until_ready(outs)
-                    trace.metric('device.dispatch_sync_s',
-                                 time.perf_counter() - t0)
-                    trace.metric('device.dispatches')
             return ctx
         except Exception as e:
             # phase-a failure frees its OWN handle (callers only see an
@@ -1545,7 +1527,8 @@ class NativeDocPool:
             # nothing on the host in both cases
             if mem is not None:
                 trace.count('ops.registers.members')
-                reg_out = register_ops.resolve_registers_members(
+                reg_out = telemetry.h2d_call(
+                    register_ops.resolve_registers_members,
                     r['t'], r['a'], r['s'], mem, r['d'].astype(bool),
                     r['ctab'], r['cidx'], window=ctx['weff'],
                     want_visible_before=False)
@@ -1553,7 +1536,8 @@ class NativeDocPool:
                 # Pallas stencil kernel on TPU (VMEM-resident pairwise
                 # temporaries), XLA twin elsewhere -- bit-equal outputs
                 from ..ops.pallas_registers import resolve_registers_auto
-                reg_out = resolve_registers_auto(
+                reg_out = telemetry.h2d_call(
+                    resolve_registers_auto,
                     r['g'], r['t'], r['a'], r['s'], r['d'].astype(bool),
                     np.ones((Tp,), bool), r['si'], r['ctab'], r['cidx'],
                     window=ctx['weff'])
@@ -1591,7 +1575,8 @@ class NativeDocPool:
         trace.count('ops.resolve_rank_dominate')
         trace.count('ops.registers.members' if mem is not None
                     else 'ops.registers.xla')
-        reg_out, rank, combo = register_ops.resolve_rank_dominate(
+        reg_out, rank, combo = telemetry.h2d_call(
+            register_ops.resolve_rank_dominate,
             r['g'], r['t'], r['a'], r['s'], r['ctab'], r['cidx'],
             r['d'].astype(bool), np.ones((Tp,), bool), r['si'],
             e['obj'], e['par'], e['ctr'], e['act'], e['val'].astype(bool),
@@ -1657,8 +1642,8 @@ class NativeDocPool:
             trace.metric('mesh.sp_engaged')
         else:
             fn = _jit_kernel(n_iters, ctx['weff'], 64)
-        reg_out, rank, combo = fn(
-            r['g'], r['t'], r['a'], r['s'], r['ctab'], r['cidx'],
+        reg_out, rank, combo = telemetry.h2d_call(
+            fn, r['g'], r['t'], r['a'], r['s'], r['ctab'], r['cidx'],
             r['d'].astype(bool), np.ones((Tp,), bool), r['si'],
             entry.par, entry.ctr, entry.act, entry.ev,
             np.int32(n_now), oe, dom_src, ov.astype(bool))
@@ -1725,7 +1710,7 @@ class NativeDocPool:
                     conf_vals = np.zeros(0, np.int32)
                 else:
                     from ..ops import registers as register_ops
-                    combo = np.asarray(ctx['combo'])
+                    combo = telemetry.d2h_read(ctx['combo'])
                     packed = np.ascontiguousarray(combo[:Tp])
                     dom_idx = np.ascontiguousarray(combo[Tp:], np.int32)
                     fallback = bool(
@@ -1754,19 +1739,15 @@ class NativeDocPool:
                              int((packed >> register_ops.PACKED_OVF_SHIFT
                                   & 1).sum()))
                 trace.metric('collect.full_matrix_readback')
-                reg_out = ctx['reg_out']
-                winner = np.ascontiguousarray(reg_out['winner'], np.int32)
-                conflicts = np.ascontiguousarray(reg_out['conflicts'],
-                                                 np.int32)
-                alive = np.ascontiguousarray(reg_out['alive_after'],
-                                             np.int32)
-                overflow = np.ascontiguousarray(reg_out['overflow'],
-                                                np.uint8)
-                winner, conflicts, alive, overflow = self._escalate(
-                    L, ctx, winner, conflicts, alive, overflow)
-                rank_arr = (np.ascontiguousarray(ctx['rank'], np.int32)
-                            if ctx['rank'] is not None
-                            else np.zeros(0, np.int32))
+                with trace.span('device.collect'):
+                    winner, conflicts, alive, overflow = \
+                        self._read_register_out(ctx['reg_out'])
+                    winner, conflicts, alive, overflow = self._escalate(
+                        L, ctx, winner, conflicts, alive, overflow)
+                    rank_arr = (np.ascontiguousarray(
+                        telemetry.d2h_read(ctx['rank']), np.int32)
+                                if ctx['rank'] is not None
+                                else np.zeros(0, np.int32))
                 hostdom = ctx.get('hostdom')
                 with trace.span('host.mid'):
                     if L.amtpu_mid(bh, ip(winner), ip(conflicts),
@@ -1780,13 +1761,8 @@ class NativeDocPool:
                         if L.amtpu_host_dominance(bh) != 0:
                             _raise_last()
                 else:
-                    t0 = time.perf_counter() if _devtime_on() else 0.0
                     with trace.span('device.dominance'):
                         self._run_dominance(L, bh)
-                    if t0:
-                        trace.metric('device.dispatch_sync_s',
-                                     time.perf_counter() - t0)
-                        trace.metric('device.dispatches')
             else:
                 hostdom = ctx.get('hostdom')
                 conf_offs = np.arange(conf_rows.size + 1,
@@ -1855,13 +1831,8 @@ class NativeDocPool:
                                    ip(alive), up(overflow),
                                    ip(rank_arr), 0) != 0:
                         _raise_last()
-            t0 = time.perf_counter() if _devtime_on() else 0.0
             with trace.span('device.dominance'):
                 self._run_dominance(L, bh)
-            if t0:
-                trace.metric('device.dispatch_sync_s',
-                             time.perf_counter() - t0)
-                trace.metric('device.dispatches')
 
         with trace.span('host.finish'):
             if L.amtpu_finish(bh) != 0:
@@ -2017,12 +1988,13 @@ class NativeDocPool:
                 rows_p[:len(sub_rows)] = sub_rows
                 sub_p = np.zeros(Tn, np.int32)
                 sub_p[:len(sub_rows)] = sub_rows
-                base = register_ops.merge_packed_rows_jit()(
+                base = telemetry.h2d_call(
+                    register_ops.merge_packed_rows_jit(),
                     base, rows_p, out['packed'], sub_p)
             trace.metric('collect.device_merge_chunks', len(esc[0]))
-            packed = np.asarray(base)
+            packed = telemetry.d2h_read(base)
         else:
-            packed = np.asarray(reg_out['packed'])
+            packed = telemetry.d2h_read(reg_out['packed'])
         if flagged.any():
             if not dev_merge:
                 packed = np.array(packed)        # writable copy
@@ -2085,7 +2057,7 @@ class NativeDocPool:
         thresh = _conf_dense_thresh()
         if thresh and conf_rows.size * thresh > Tp:
             trace.metric('collect.conflict_dense')
-            allconf = np.asarray(reg_out['conflicts'])
+            allconf = telemetry.d2h_read(reg_out['conflicts'])
             return np.ascontiguousarray(allconf[conf_rows], np.int32)
         if conf_rows.size:
             trace.metric('collect.conflict_sparse')
@@ -2102,8 +2074,9 @@ class NativeDocPool:
             pad *= 2
         rows_p = np.zeros((pad,), np.int32)
         rows_p[:rows.size] = rows
-        got = np.asarray(register_ops.gather_rows(
-            reg_out['conflicts'], rows_p))[:rows.size]
+        got = telemetry.d2h_read(telemetry.h2d_call(
+            register_ops.gather_rows, reg_out['conflicts'],
+            rows_p))[:rows.size]
         return np.ascontiguousarray(got, np.int32)
 
     def _gather_conflicts(self, reg_out, alive, Tp):
@@ -2137,21 +2110,25 @@ class NativeDocPool:
             trace.count('ops.registers.members' if mem is not None
                         else 'ops.registers.xla')
         if Tp > 0 and Lp > 0:
-            reg_out, rank = register_ops.resolve_and_rank(
+            reg_out, rank = telemetry.h2d_call(
+                register_ops.resolve_and_rank,
                 r['g'], r['t'], r['a'], r['s'], r['ctab'], r['cidx'],
                 r['d'].astype(bool), np.ones((Tp,), bool), r['si'],
                 e['obj'], e['par'], e['ctr'], e['act'],
                 e['val'].astype(bool), e['lsi'], n_iters,
                 window=weff, mem_idx=mem)
-            return reg_out, np.asarray(rank)
+            with trace.span('device.collect'):
+                return reg_out, telemetry.d2h_read(rank)
         if Tp > 0:
             if mem is not None:
-                reg_out = register_ops.resolve_registers_members(
+                reg_out = telemetry.h2d_call(
+                    register_ops.resolve_registers_members,
                     r['t'], r['a'], r['s'], mem, r['d'].astype(bool),
                     r['ctab'], r['cidx'], window=weff,
                     want_visible_before=False)
             else:
-                reg_out = register_ops.resolve_registers(
+                reg_out = telemetry.h2d_call(
+                    register_ops.resolve_registers,
                     r['g'], r['t'], r['a'], r['s'],
                     is_del=r['d'].astype(bool),
                     alive_in=np.ones((Tp,), bool), window=weff,
@@ -2159,27 +2136,34 @@ class NativeDocPool:
                     clock_idx=r['cidx'])
             return reg_out, np.zeros((0,), np.int32)
         if Lp > 0:
-            rank = np.asarray(list_rank.linearize(
-                e['obj'], e['par'], e['ctr'], e['act'],
-                e['val'].astype(bool), n_iters, sort_idx=e['lsi']))
-            return None, rank
+            rank = telemetry.h2d_call(list_rank.linearize,
+                                      e['obj'], e['par'], e['ctr'], e['act'],
+                                      e['val'].astype(bool), n_iters,
+                                      sort_idx=e['lsi'])
+            with trace.span('device.collect'):
+                return None, telemetry.d2h_read(rank)
         return None, np.zeros((0,), np.int32)
 
     def _unpack_register_out(self, reg_out, Tp):
         """One packed [Tp] i32 transfer for winner/alive/overflow plus a
         lazy row-gather of conflicts only where a register kept >1 member
         (D2H over the device link is the scarce resource, not compute)."""
-        from ..ops import registers as register_ops
         if Tp >= 1 << 24:    # packed winner field width exceeded
-            winner = np.ascontiguousarray(reg_out['winner'], np.int32)
-            conflicts = np.ascontiguousarray(reg_out['conflicts'], np.int32)
-            alive = np.ascontiguousarray(reg_out['alive_after'], np.int32)
-            overflow = np.ascontiguousarray(reg_out['overflow'], np.uint8)
-            return winner, conflicts, alive, overflow
-        packed = np.asarray(reg_out['packed'])
+            return self._read_register_out(reg_out)
+        packed = telemetry.d2h_read(reg_out['packed'])
         winner, alive, overflow = self._unpack_packed(packed)
         conflicts = self._gather_conflicts(reg_out, alive, Tp)
         return winner, conflicts, alive, overflow
+
+    @staticmethod
+    def _read_register_out(reg_out):
+        """The full winner/conflicts/alive/overflow matrices, read back
+        whole (the fallback paths)."""
+        read = telemetry.d2h_read
+        return (np.ascontiguousarray(read(reg_out['winner']), np.int32),
+                np.ascontiguousarray(read(reg_out['conflicts']), np.int32),
+                np.ascontiguousarray(read(reg_out['alive_after']), np.int32),
+                np.ascontiguousarray(read(reg_out['overflow']), np.uint8))
 
     @staticmethod
     def _unpack_packed(packed):
@@ -2227,8 +2211,10 @@ class NativeDocPool:
                                        shape=(W, Tp))
             w_cap = max(1, min(CAP // (Lp * 64 * 4), CAP // (Tp * 4)))
             if W <= w_cap:
-                idx = np.asarray(dominance_grouped_auto(
-                    v0, er, oe, orank, od, ov.astype(bool), chunk=64))
+                idx = telemetry.h2d_call(dominance_grouped_auto, v0, er, oe,
+                                         orank, od, ov.astype(bool), chunk=64)
+                with trace.span('device.collect'):
+                    idx = telemetry.d2h_read(idx)
             else:
                 idx = np.empty((W, Tp), np.int32)
                 for s in range(0, W, w_cap):
@@ -2243,11 +2229,13 @@ class NativeDocPool:
                         out[:n] = x[s:hi]
                         return out
 
-                    got = np.asarray(dominance_grouped_auto(
+                    got = telemetry.h2d_call(
+                        dominance_grouped_auto,
                         pad(v0, 0.0), pad(er, -1), pad(oe, -1),
                         pad(orank, -1), pad(od, 0),
-                        pad(ov, 0).astype(bool), chunk=64))
-                    idx[s:hi] = got[:n]
+                        pad(ov, 0).astype(bool), chunk=64)
+                    with trace.span('device.collect'):
+                        idx[s:hi] = telemetry.d2h_read(got)[:n]
             idx = np.ascontiguousarray(idx, np.int32)
             L.amtpu_dom_set_indexes(
                 bh, blk, idx.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
@@ -3124,12 +3112,38 @@ class ShardedNativePool:
         return ids, np.concatenate(mats, axis=0)
 
 
+_compile_watch_lock = threading.Lock()
+_compile_watched = False
+
+
+def _watch_compiles():
+    """Counts every backend compile in this process, once registered:
+    `jit.compiles`, `jit.compile_s` and `jit.compiles.<fun_name>`, from
+    JAX's own compile-duration event (a persistent-cache read fires it
+    too).  Registered once, by the first `make_pool()`."""
+    global _compile_watched
+    with _compile_watch_lock:
+        if _compile_watched:
+            return
+        _compile_watched = True
+    import jax
+
+    def on_duration(event, secs, fun_name=None, **_):
+        if event != '/jax/core/compile/backend_compile_duration':
+            return
+        telemetry.metric('jit.compiles')
+        telemetry.metric('jit.compile_s', secs)
+        telemetry.metric('jit.compiles.%s' % fun_name)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+
 def make_pool():
     """The execution-mode-aware pool factory (ISSUE 7): `MeshDocPool`
     when ``AMTPU_MESH=dp[,sp]`` requests mesh execution, else a plain
     `NativeDocPool`.  The sidecar backend and the CI gates construct
     through this, so flipping one env var moves a whole serving stack
     (gateway, resilience, sidecar) onto the device mesh unchanged."""
+    _watch_compiles()
     mesh = parse_mesh_env()
     if mesh is None:
         return NativeDocPool()

@@ -40,21 +40,25 @@ from ..analysis import sanitize
 from .resident import _bucket_pow2
 
 
+def clock_table_scatter(tab, idx, rows):
+    """The clock table's delta rows: pad slots carry idx == capacity (out
+    of bounds) and drop.  Jitted, its XLA module is
+    `jit_clock_table_scatter`."""
+    return tab.at[idx].set(rows, mode='drop')
+
+
 @lru_cache(maxsize=None)
 def _jit_row_scatter(donate):
     import jax
 
-    def scatter(tab, idx, rows):
-        # pad slots carry idx == capacity (out of bounds) and drop
-        return tab.at[idx].set(rows, mode='drop')
     if donate:
         # accelerators: reuse the prior table's device buffer for the
         # output instead of allocating per delta (donate_argnums is
         # proven on the tier staging path, ops/registers.py); on CPU
         # "transfers" are memcpys and donation buys nothing
-        jitted = jax.jit(scatter, donate_argnums=(0,))
+        jitted = jax.jit(clock_table_scatter, donate_argnums=(0,))
     else:
-        jitted = jax.jit(scatter)
+        jitted = jax.jit(clock_table_scatter)
 
     def dispatch(tab, idx, rows):
         # jax zero-copies 64B-aligned numpy inputs on CPU and even
@@ -64,6 +68,7 @@ def _jit_row_scatter(donate):
         # computation PRIVATE synchronous host copies instead: jax may
         # alias them freely because no caller ever sees them, so the
         # staging arrays are reusable the moment dispatch returns.
+        trace.metric('transfer.h2d_bytes', idx.nbytes + rows.nbytes)
         out = jitted(tab, np.array(idx), np.array(rows))
         # AMTPU_SANITIZE=1: poison the caller-visible staging arrays the
         # moment dispatch returns -- if the private-copy contract above
@@ -97,13 +102,20 @@ class PoolClockCache:
         the wave-pipelined driver hands the PREVIOUS table version to a
         batch whose kernels are still in flight when the next wave's
         delta runs, so donating would recycle a buffer an enqueued
-        computation may still read."""
-        import jax
-        import jax.numpy as jnp
-
+        computation may still read.  The staging is the `device.upload`
+        span."""
         info = (ctypes.c_int64 * 4)()
         L.amtpu_resclk_info(pool, info)
         n, ap, gen = int(info[0]), int(info[1]), int(info[2])
+        with trace.span('device.upload'):
+            self._sync(L, pool, n, ap, gen, donate_ok)
+        return self.tab
+
+    def _sync(self, L, pool, n, ap, gen, donate_ok):
+        """Brings `self.tab` to the pool's (n, ap, gen) state."""
+        import jax
+        import jax.numpy as jnp
+
         need_full = (self.tab is None or gen != self.gen
                      or ap != self.ap or n < self.n)
         if not need_full and n > self.cap:
@@ -127,6 +139,7 @@ class PoolClockCache:
                                             shape=(n, ap))
                 host[:n] = src
             self.tab = jnp.asarray(host)
+            trace.metric('transfer.h2d_bytes', host.nbytes)
             trace.metric('resident.batch_full_uploads')
             trace.metric('resident.batch_full_upload_rows', n)
             self.cap = cap
@@ -148,7 +161,6 @@ class PoolClockCache:
             trace.metric('resident.batch_hits')
             trace.metric('resident.batch_noop')
         self.gen, self.n, self.ap = gen, n, ap
-        return self.tab
 
     def drop_if_disabled(self, L, pool):
         """Release the device table once C++ permanently disabled the

@@ -100,15 +100,21 @@ class ResidentArena:
         self.dirty = False
 
 
+def arena_scatter(col, idx, vals):
+    """One resident column's delta: pad slots carry idx == capacity (out
+    of bounds) and drop.  Jitted, its XLA module is `jit_arena_scatter`."""
+    return col.at[idx].set(vals, mode='drop')
+
+
 @lru_cache(maxsize=None)
 def _jit_scatter(sharding=None):
     import jax
+    jitted = jax.jit(arena_scatter, out_shardings=sharding)
 
-    @partial(jax.jit, out_shardings=sharding)
-    def scatter(col, idx, vals):
-        # pad slots carry idx == capacity (out of bounds) and drop
-        return col.at[idx].set(vals, mode='drop')
-    return scatter
+    def dispatch(col, idx, vals):
+        trace.metric('transfer.h2d_bytes', idx.nbytes + vals.nbytes)
+        return jitted(col, idx, vals)
+    return dispatch
 
 
 @lru_cache(maxsize=None)
@@ -326,15 +332,17 @@ class ResidentCache:
             sharding = _sp_sharding(capacity, cap=self.sp_cap)
 
             def up(a, dtype, fill):
-                arr = jnp.asarray(np.pad(
-                    np.ascontiguousarray(a[:n_now], dtype),
-                    (0, pad), constant_values=fill))
+                host = np.pad(np.ascontiguousarray(a[:n_now], dtype),
+                              (0, pad), constant_values=fill)
+                trace.metric('transfer.h2d_bytes', host.nbytes)
+                arr = jnp.asarray(host)
                 return (jax.device_put(arr, sharding)
                         if sharding is not None else arr)
-            entry.par = up(par, np.int32, -1)
-            entry.ctr = up(ctr, np.int32, 0)
-            entry.act = up(ranks, np.int32, 0)
-            entry.ev = up(vis, np.float32, 0.0)
+            with trace.span('device.upload'):
+                entry.par = up(par, np.int32, -1)
+                entry.ctr = up(ctr, np.int32, 0)
+                entry.act = up(ranks, np.int32, 0)
+                entry.ev = up(vis, np.float32, 0.0)
             entry.n = n_now
             self.entries[key] = entry
             trace.count('resident.full_upload_rows', n_now)
@@ -349,13 +357,14 @@ class ResidentCache:
                 out = np.zeros(kp, dtype)
                 out[:k] = a
                 return out
-            entry.par = scatter(entry.par, idx,
-                                pad(par[lo:n_now], np.int32))
-            entry.ctr = scatter(entry.ctr, idx,
-                                pad(ctr[lo:n_now], np.int32))
-            entry.act = scatter(entry.act, idx, pad(ranks, np.int32))
-            entry.ev = scatter(entry.ev, idx,
-                               pad(vis[lo:n_now], np.float32))
+            with trace.span('device.upload'):
+                entry.par = scatter(entry.par, idx,
+                                    pad(par[lo:n_now], np.int32))
+                entry.ctr = scatter(entry.ctr, idx,
+                                    pad(ctr[lo:n_now], np.int32))
+                entry.act = scatter(entry.act, idx, pad(ranks, np.int32))
+                entry.ev = scatter(entry.ev, idx,
+                                   pad(vis[lo:n_now], np.float32))
             entry.n = n_now
             trace.count('resident.delta_upload_rows', k)
         else:
@@ -377,8 +386,9 @@ class ResidentCache:
             idx[:touched_eidx.size] = touched_eidx
             vals = np.zeros(kp, np.float32)
             vals[:touched_eidx.size] = vis[touched_eidx]
-            entry.ev = _jit_scatter(
-                _sp_sharding(entry.capacity, cap=self.sp_cap))(
-                    entry.ev, idx, vals)
+            with trace.span('device.upload'):
+                entry.ev = _jit_scatter(
+                    _sp_sharding(entry.capacity, cap=self.sp_cap))(
+                        entry.ev, idx, vals)
         entry.n = n_now
         entry.dirty = False

@@ -876,7 +876,8 @@ def escalate_dispatch_groups(groups, time, actor, seq, is_del,
                 return out
 
             with telemetry.span('device.escalate', tier=W, rows=n):
-                out = members_tier_jit()(
+                out = telemetry.h2d_call(
+                    members_tier_jit(),
                     pad('time', time, 0), pad('actor', actor, 0),
                     pad('seq', seq, 0), mem, pad('isdel', is_del, False),
                     clock_table, pad('cidx', clock_idx, 0), window=W,
@@ -913,16 +914,19 @@ def escalate_overflow_collect_arrays(pending, need_winner=True):
     .winner is None): the device-merge path (`merge_packed_rows_jit`)
     already scattered the tier winners into the packed word on device,
     so the collect half only owes conflicts + aliveness."""
+    from .. import telemetry
+    read = telemetry.d2h_read
+
     chunks = []
     for W, sub_rows, out in pending:
         n = len(sub_rows)
         sub = np.ascontiguousarray(sub_rows, np.int64)
-        win = np.asarray(out['winner'])[:n] if need_winner else None
-        alive = np.ascontiguousarray(np.asarray(out['alive_after'])[:n],
+        win = read(out['winner'])[:n] if need_winner else None
+        alive = np.ascontiguousarray(read(out['alive_after'])[:n],
                                      np.int32)
         if 'visible_before' in out:
             vb = np.ascontiguousarray(
-                np.asarray(out['visible_before'])[:n], bool)
+                read(out['visible_before'])[:n], bool)
         else:
             vb = np.zeros((n,), bool)
         conf_rows = np.nonzero(alive > 1)[0].astype(np.int32)
@@ -933,8 +937,8 @@ def escalate_overflow_collect_arrays(pending, need_winner=True):
                 padlen *= 2
             rows_p = np.zeros((padlen,), np.int32)
             rows_p[:conf_rows.size] = conf_rows
-            conf = np.asarray(gather_rows(out['conflicts'],
-                                          rows_p))[:conf_rows.size]
+            conf = read(telemetry.h2d_call(
+                gather_rows, out['conflicts'], rows_p))[:conf_rows.size]
             conf_g = np.where(conf >= 0, sub[np.clip(conf, 0, n - 1)],
                               -1).astype(np.int32)
         win_g = None

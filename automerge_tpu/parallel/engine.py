@@ -710,19 +710,11 @@ class TPUDocPool:
             c_arr[:T, :A] = np.stack(clock_rows)
             d_arr = np.zeros((Tp,), bool)
             d_arr[:T] = d_col
-            # device-time attribution: np.asarray blocks on the device
-            # outputs, so under AMTPU_DEVTIME the perf_counter pair IS
-            # the synchronous dispatch+compute time (host occupancy and
-            # device time report separately; docs/OBSERVABILITY.md)
-            devtime = telemetry.devtime_on()
-            t0 = time.perf_counter() if devtime else 0.0
             reg_out = register_ops.resolve_registers(
                 g_arr, t_arr, a_arr, s_arr, c_arr, d_arr,
                 np.ones((Tp,), bool),
                 sort_idx=np.lexsort((t_arr, g_arr)).astype(np.int32))
             reg_out = {k: np.asarray(v)[:T] for k, v in reg_out.items()}
-            if devtime:
-                telemetry.observe_device_dispatch(time.perf_counter() - t0)
         else:
             reg_out = None
 
@@ -763,15 +755,11 @@ class TPUDocPool:
             skey_obj = np.where(val_arr, obj_arr, 2 ** 30)
             sort_idx = np.lexsort(
                 (-act_arr, -ctr_arr, par_arr, skey_obj)).astype(np.int32)
-            devtime = telemetry.devtime_on()
-            t0 = time.perf_counter() if devtime else 0.0
             # doubling depth bound: DFS chains never cross objects
             rank = np.asarray(list_rank.linearize(
                 obj_arr, par_arr, ctr_arr, act_arr, val_arr,
                 n_iters=list_rank.ceil_log2(max(max_obj_len, 1)) + 1,
                 sort_idx=sort_idx))[:L]
-            if devtime:
-                telemetry.observe_device_dispatch(time.perf_counter() - t0)
         else:
             rank = np.zeros((0,), np.int32)
 
@@ -931,13 +919,8 @@ class TPUDocPool:
                         orank[o, t] = rank[base + eidx]
                         od[o, t] = delta
                         ov[o, t] = True
-                devtime = telemetry.devtime_on()
-                t0 = time.perf_counter() if devtime else 0.0
                 idxs = np.asarray(dominance_grouped_auto(
                     v0, er, oe, orank, od, ov, chunk=K))
-                if devtime:
-                    telemetry.observe_device_dispatch(
-                        time.perf_counter() - t0)
                 for o, akey in enumerate(slab):
                     for t, (op_idx, row, _e, _d) in enumerate(obj_ops[akey]):
                         out[op_idx] = (int(idxs[o, t]), row)

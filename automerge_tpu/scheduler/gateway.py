@@ -194,12 +194,13 @@ class _Conn(object):
         if self.closed:
             return
         try:
-            if self.gateway.use_msgpack:
-                import msgpack
-                body = msgpack.packb(resp, use_bin_type=True)
-                frame = struct.pack('>I', len(body)) + body
-            else:
-                frame = (json.dumps(resp) + '\n').encode()
+            with telemetry.span('gateway.encode'):
+                if self.gateway.use_msgpack:
+                    import msgpack
+                    body = msgpack.packb(resp, use_bin_type=True)
+                    frame = struct.pack('>I', len(body)) + body
+                else:
+                    frame = (json.dumps(resp) + '\n').encode()
         except (TypeError, ValueError):
             return
         self.egress.stage(frame, kind='response')
@@ -248,14 +249,17 @@ class _Conn(object):
             line = line.strip()
             if not line:
                 continue
-            try:
-                req = json.loads(line)
-            except ValueError as e:
-                self.send({'id': None, 'error': 'bad json: %s' % e,
-                           'errorType': 'RangeError'})
-                continue
-            self._frame_fault()
-            self.gateway.submit(self, req, t0=t0)
+            with telemetry.span('gateway.decode'):
+                try:
+                    req = json.loads(line)
+                except ValueError as e:
+                    self.send({'id': None, 'error': 'bad json: %s' % e,
+                               'errorType': 'RangeError'})
+                    continue
+                self._frame_fault()
+                inline = self.gateway.route(self, req, t0=t0)
+            if inline is not None:
+                inline()
 
     def _run_msgpack(self):
         import msgpack
@@ -268,17 +272,20 @@ class _Conn(object):
             if len(body) < n:
                 break
             t0 = time.perf_counter()    # frame receipt (see _run_jsonl)
-            try:
-                req = msgpack.unpackb(body, raw=False,
-                                      strict_map_key=False)
-                if not isinstance(req, dict):
-                    raise ValueError('request is not a map')
-            except Exception as e:
-                self.send({'id': None, 'error': 'bad msgpack: %s' % e,
-                           'errorType': 'RangeError'})
-                continue
-            self._frame_fault()
-            self.gateway.submit(self, req, t0=t0)
+            with telemetry.span('gateway.decode'):
+                try:
+                    req = msgpack.unpackb(body, raw=False,
+                                          strict_map_key=False)
+                    if not isinstance(req, dict):
+                        raise ValueError('request is not a map')
+                except Exception as e:
+                    self.send({'id': None, 'error': 'bad msgpack: %s' % e,
+                               'errorType': 'RangeError'})
+                    continue
+                self._frame_fault()
+                inline = self.gateway.route(self, req, t0=t0)
+            if inline is not None:
+                inline()
 
     def close(self):
         self.closed = True
@@ -417,7 +424,11 @@ class GatewayServer(object):
     def serve_forever(self):
         self.start()
         try:
-            self._dispatch_thread.join()
+            # a timed join: a signal the kernel hands to another thread
+            # only runs its Python handler once the main thread wakes,
+            # and an untimed join never does
+            while self._dispatch_thread.is_alive():
+                self._dispatch_thread.join(0.5)
         except KeyboardInterrupt:
             self.stop()
 
@@ -560,16 +571,26 @@ class GatewayServer(object):
     # -- request routing ------------------------------------------------
 
     def submit(self, conn, req, t0=None):
-        """Routes one decoded request.  Runs on the connection's reader
-        thread; anything that can block on the pool or the queue must
-        not stall OTHER connections (it only stalls this reader).
-        `t0` is the frame-receipt timestamp the reader stamped before
-        decoding -- attribution backdates each Clock to it."""
+        """Routes one decoded request and runs whatever it must run
+        inline.  Runs on the connection's reader thread; anything that
+        can block on the pool or the queue must not stall OTHER
+        connections (it only stalls this reader).  `t0` is the
+        frame-receipt timestamp the reader stamped before decoding --
+        attribution backdates each Clock to it."""
+        inline = self.route(conn, req, t0=t0)
+        if inline is not None:
+            inline()
+
+    def route(self, conn, req, t0=None):
+        """The admission half of `submit`: offers queued ops and answers
+        refusals, and returns the work the request must run inline on
+        the reader (a bypass read's pool-lock wait + handle, pure
+        commands, the serial backend's error answers) as a callable, or
+        None.  The readers time this half alone as `gateway.decode`."""
         cmd = req.get('cmd')
         rid = req.get('id')
         if cmd in PURE_CMDS:
-            conn.send(self.backend.handle(req))
-            return
+            return lambda: conn.send(self.backend.handle(req))
         if self.read_only and (cmd in BATCH_CMDS or cmd in EXEC_CMDS
                                or cmd in ROUTER_CMDS):
             # a read replica's listener refuses mutations with a typed
@@ -665,34 +686,7 @@ class GatewayServer(object):
                                           t0=t0,
                                           trace=req.get('trace'))
                 clock.mark('admit')
-                with self.pool_lock:
-                    if docs is not None and self.storage_tier \
-                            is not None:
-                        # a read of a cold doc reloads it on touch --
-                        # transparently, under the same pool lock the
-                        # flush path uses.  A FAILED reload answers a
-                        # typed error (reading the missing doc would
-                        # silently serve empty state)
-                        failed = self.storage_tier.ensure_resident(
-                            docs)
-                        if failed:
-                            d, e = next(iter(failed.items()))
-                            resp = self._cold_error(rid, d, e)
-                        else:
-                            self.storage_tier.note_touch(docs)
-                            resp = self.backend.handle(req)
-                    else:
-                        resp = self.backend.handle(req)
-                # send + finish OUTSIDE the pool lock: a failed read's
-                # finish() may snapshot the recorder ring and write an
-                # exemplar -- never on the lock every flush needs
-                clock.mark('dispatch')
-                conn.send(resp)
-                clock.mark('emit')
-                attribution.finish(clock, ok='error' not in resp,
-                                   cmd=cmd, rid=rid,
-                                   doc=docs[0] if docs else None)
-                return
+                return lambda: self._bypass_read(conn, req, docs, clock)
             op = PendingOp(conn, rid, cmd, req, docs, 1, batchable=False)
             op.clock = attribution.Clock(attribution.class_of(cmd), t0=t0,
                                          trace=req.get('trace'))
@@ -710,9 +704,10 @@ class GatewayServer(object):
                 # malformed routing fields: the serial backend's error
                 # contract answers (missing field -> RangeError, bad
                 # type -> TypeError), nothing mutates
-                with self.pool_lock:
-                    conn.send(self.backend.handle(req))
-                return
+                def answer():
+                    with self.pool_lock:
+                        conn.send(self.backend.handle(req))
+                return answer
             op = PendingOp(conn, rid, cmd, req, docs,
                            _op_weight(cmd, req),
                            batchable=(cmd in BATCH_CMDS))
@@ -727,7 +722,35 @@ class GatewayServer(object):
                            'retryAfterMs': e.retry_after_ms})
             return
         # unknown command: the serial backend's RangeError contract
-        conn.send(self.backend.handle(req))
+        return lambda: conn.send(self.backend.handle(req))
+
+    def _bypass_read(self, conn, req, docs, clock):
+        """Answers a read on the reader thread, under the pool lock (no
+        queued mutation of its doc to reorder against)."""
+        cmd, rid = req.get('cmd'), req.get('id')
+        with self.pool_lock:
+            if docs is not None and self.storage_tier is not None:
+                # a read of a cold doc reloads it on touch --
+                # transparently, under the same pool lock the flush path
+                # uses.  A FAILED reload answers a typed error (reading
+                # the missing doc would silently serve empty state)
+                failed = self.storage_tier.ensure_resident(docs)
+                if failed:
+                    d, e = next(iter(failed.items()))
+                    resp = self._cold_error(rid, d, e)
+                else:
+                    self.storage_tier.note_touch(docs)
+                    resp = self.backend.handle(req)
+            else:
+                resp = self.backend.handle(req)
+        # send + finish OUTSIDE the pool lock: a failed read's finish()
+        # may snapshot the recorder ring and write an exemplar -- never
+        # on the lock every flush needs
+        clock.mark('dispatch')
+        conn.send(resp)
+        clock.mark('emit')
+        attribution.finish(clock, ok='error' not in resp, cmd=cmd, rid=rid,
+                           doc=docs[0] if docs else None)
 
     # -- the dispatcher -------------------------------------------------
 
@@ -735,9 +758,10 @@ class GatewayServer(object):
         deadline = flush_deadline_s()
         mdocs, mops = max_batch_docs(), max_batch_ops()
         while True:
-            if not self.queue.wait_for_work(deadline, mdocs, mops):
-                return          # closed and drained
-            batch, execs = self.queue.claim(mdocs, mops)
+            with telemetry.span('scheduler.wait'):
+                if not self.queue.wait_for_work(deadline, mdocs, mops):
+                    return          # closed and drained
+                batch, execs = self.queue.claim(mdocs, mops)
             if not batch and not execs:
                 continue
             try:

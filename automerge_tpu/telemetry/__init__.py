@@ -19,8 +19,9 @@ pieces, threaded through every layer of the stack (frontend -> sidecar
 
 The always-on flat map (`metric` / `metrics_snapshot`) is kept verbatim
 from trace.py: the handful of numbers every bench line must report
-unconditionally -- oracle-fallback and degradation counters, measured
-device seconds.  Incremented once per BATCH, never per op.
+unconditionally -- oracle-fallback and degradation counters, bytes
+moved between host and device, backend compiles.  Incremented once per
+BATCH or event, never per op.
 
 `automerge_tpu.trace` remains as a compatibility shim over this module,
 so pre-PR-1 call sites and the `trace.ENABLED = True` toggle keep
@@ -31,7 +32,7 @@ Metric catalog: docs/OBSERVABILITY.md.
 
 import threading
 import time
-from ..utils.common import env_bool, env_float, env_int
+from ..utils.common import env_float, env_int
 
 from .metrics import (DEFAULT_BUCKETS, MetricRegistry,  # noqa: F401
                       format_value)
@@ -618,7 +619,7 @@ def metrics_snapshot():
 
 
 # ---------------------------------------------------------------------------
-# batch + device helpers (the per-layer call sites)
+# batch helpers (the per-layer call sites)
 # ---------------------------------------------------------------------------
 
 def observe_batch(pool, seconds, docs=0, ops=0):
@@ -636,17 +637,25 @@ def observe_batch(pool, seconds, docs=0, ops=0):
     recorder.record('batch.commit', n=docs, detail=pool)
 
 
-def devtime_on():
-    """AMTPU_DEVTIME=1: synchronous per-dispatch device timing (checked
-    per call, not latched -- bench.py flips it for one dedicated pass)."""
-    return env_bool('AMTPU_DEVTIME', False)
+def h2d_call(fn, *args, **kwargs):
+    """Calls the jitted kernel `fn`, counting the bytes of the host
+    arrays it is handed (`transfer.h2d_bytes`; device-resident inputs
+    move nothing)."""
+    import numpy as np
+    metric('transfer.h2d_bytes', sum(
+        a.nbytes for a in args + tuple(kwargs.values())
+        if isinstance(a, np.ndarray)))
+    return fn(*args, **kwargs)
 
 
-def observe_device_dispatch(seconds, n=1):
-    """One synchronous (block_until_ready) kernel dispatch measured:
-    lands in the flat map under the names bench.py already reads."""
-    metric('device.dispatch_sync_s', seconds)
-    metric('device.dispatches', n)
+def d2h_read(x):
+    """Reads the device array `x` back to the host (blocking on it),
+    counting its bytes (`transfer.d2h_bytes`).  Callers sit inside a
+    `device.collect` span."""
+    import numpy as np
+    a = np.asarray(x)
+    metric('transfer.d2h_bytes', a.nbytes)
+    return a
 
 
 # ---------------------------------------------------------------------------
@@ -683,7 +692,7 @@ def _render_derived(out):
     for k, v in flat.items():
         if k.startswith('fallback.'):
             fallbacks[k.split('.', 1)[1]] = v
-        elif k not in ('device.dispatch_sync_s', 'device.dispatches'):
+        else:
             rest[k] = v
     out.append('# HELP amtpu_fallback_total Oracle-fallback / degradation '
                'events by reason (always on; nonzero means a batch left '
@@ -693,18 +702,6 @@ def _render_derived(out):
         out.append('amtpu_fallback_total%s %s' % (
             _labels_text(('reason',), (reason,)),
             format_value(fallbacks[reason])))
-    out.append('# HELP amtpu_device_seconds_total Measured synchronous '
-               'device time (block_until_ready; populated under '
-               'AMTPU_DEVTIME=1)')
-    out.append('# TYPE amtpu_device_seconds_total counter')
-    out.append('amtpu_device_seconds_total %s'
-               % format_value(float(flat.get('device.dispatch_sync_s',
-                                             0.0))))
-    out.append('# HELP amtpu_device_dispatches_total Synchronously '
-               'measured kernel dispatches (AMTPU_DEVTIME=1)')
-    out.append('# TYPE amtpu_device_dispatches_total counter')
-    out.append('amtpu_device_dispatches_total %s'
-               % format_value(float(flat.get('device.dispatches', 0.0))))
     out.append('# HELP amtpu_runtime_counter Remaining always-on flat '
                'counters, exported verbatim by name')
     out.append('# TYPE amtpu_runtime_counter gauge')
@@ -777,7 +774,7 @@ def healthz():
 
 
 def bench_block():
-    """The per-BENCH-line embed: fallback rates, device seconds, batch
+    """The per-BENCH-line embed: fallback rates, counter blocks, batch
     latency summaries, and (when tracing) the phase occupancy table."""
     flat = metrics_snapshot()
     fallbacks = {r: 0.0 for r in KNOWN_FALLBACK_REASONS}
@@ -877,8 +874,6 @@ def bench_block():
         'migrate': migrate,
         'failover': failover,
         'readview': readview,
-        'device_s': round(flat.get('device.dispatch_sync_s', 0.0), 4),
-        'device_dispatches': int(flat.get('device.dispatches', 0)),
         'batch_latency': BATCH_LATENCY.snapshot() or {},
         'ops_total': OPS.value,
         'docs_total': DOCS.value,

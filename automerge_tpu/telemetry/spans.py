@@ -26,9 +26,20 @@ Propagation is contextvars-based: nesting follows the call stack within
 a thread/async context.  Worker threads (ShardedNativePool) start fresh
 contexts, so their spans begin new traces -- their timings still land in
 the shared occupancy table, which is the cross-thread aggregate.
+
+The device trace's clock: while tracing is enabled and JAX is already
+imported, every span also writes itself into JAX's profiler trace as a
+`jax.profiler.TraceAnnotation` on the thread that runs it, as SELF time:
+a thread's line shows only its innermost open span (a child's opening
+closes the parent's annotation, its closing reopens one under the
+parent's name).  A device idle gap then overlaps the span the host was
+actually in.  Python garbage collection is timed and annotated the same
+way as `runtime.gc` (a `gc.callbacks` hook, registered while enabled).
+This module never imports JAX itself.
 """
 
 import contextvars
+import gc
 import json
 import os
 import sys
@@ -54,7 +65,7 @@ class _State(object):
 
 
 _state = _State()
-_state.on = env_bool('AMTPU_TRACE', False)
+_state.on = False
 
 
 def enabled():
@@ -63,10 +74,124 @@ def enabled():
 
 def enable():
     _state.on = True
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
 
 
 def disable():
     _state.on = False
+    if _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+
+
+# ---------------------------------------------------------------------------
+# self-time annotations in JAX's profiler trace
+# ---------------------------------------------------------------------------
+
+class _Frames(threading.local):
+    """This thread's open program annotations, outermost first: each
+    frame is [name, open TraceAnnotation or None]; only the last one
+    may hold an open annotation.  `busy` is set while the stack is being
+    rewritten, so a collection that fires inside (an allocation) leaves
+    it alone."""
+
+    def __init__(self):
+        self.stack = []
+        self.busy = False
+
+
+_frames = _Frames()
+_annotation = []        # [jax.profiler.TraceAnnotation] once bound
+
+
+def _annotation_cls():
+    """`jax.profiler.TraceAnnotation`, bound at the first span after JAX
+    was imported by someone else; None before that."""
+    if _annotation:
+        return _annotation[0]
+    prof = sys.modules.get('jax.profiler')
+    cls = getattr(prof, 'TraceAnnotation', None)
+    if cls is not None:
+        _annotation.append(cls)
+    return cls
+
+
+def _close(frame):
+    ann, frame[1] = frame[1], None
+    if ann is not None:
+        ann.__exit__(None, None, None)
+
+
+def _open(cls, frame):
+    if cls.is_enabled():
+        ann = cls(frame[0])
+        ann.__enter__()
+        frame[1] = ann
+
+
+def _push(name):
+    """Opens `name` as this thread's innermost annotation, closing the
+    one below it.  Returns the frame for `_pop`, or None where there is
+    no JAX to annotate (or a collection interrupted a rewrite)."""
+    cls = _annotation_cls()
+    tl = _frames
+    if cls is None or tl.busy:
+        return None
+    tl.busy = True
+    try:
+        stack = tl.stack
+        if stack:
+            _close(stack[-1])
+        frame = [name, None]
+        stack.append(frame)
+        _open(cls, frame)
+    finally:
+        tl.busy = False
+    return frame
+
+
+def _pop(frame):
+    """Closes `frame`'s annotation and reopens the one below it under
+    its own name (spans nest LIFO; a frame found deeper is dropped)."""
+    tl = _frames
+    if tl.busy:
+        return
+    tl.busy = True
+    try:
+        _close(frame)
+        stack = tl.stack
+        if stack and stack[-1] is frame:
+            stack.pop()
+            if stack:
+                _open(_annotation[0], stack[-1])
+        elif frame in stack:
+            stack.remove(frame)
+    finally:
+        tl.busy = False
+
+
+# Python garbage collection as the `runtime.gc` phase.  CPython runs one
+# collection at a time, so the callback's state needs no lock -- and may
+# take none: a collection can start inside a section holding `_lock`.
+_gc = {'s': 0.0, 'n': 0, 't0': 0.0, 'frame': None}
+
+
+def _on_gc(phase, _info):
+    if phase == 'start':
+        _gc['frame'] = _push('runtime.gc')
+        _gc['t0'] = time.perf_counter()
+        return
+    if _gc['t0']:            # else registered mid-collection
+        _gc['s'] += time.perf_counter() - _gc['t0']
+        _gc['n'] += 1
+        _gc['t0'] = 0.0
+    frame, _gc['frame'] = _gc['frame'], None
+    if frame is not None:
+        _pop(frame)
+
+
+if env_bool('AMTPU_TRACE', False):
+    enable()
 
 
 def new_id():
@@ -108,7 +233,7 @@ NULL_SPAN = _NullSpan()
 
 class Span(object):
     __slots__ = ('name', 'trace_id', 'span_id', 'parent_id', 'attrs',
-                 'start', '_t0', '_token')
+                 'start', '_t0', '_token', '_frame')
 
     def __init__(self, name, trace_id, parent_id, attrs):
         self.name = name
@@ -122,12 +247,15 @@ class Span(object):
 
     def __enter__(self):
         self._token = _current.set(self)
+        self._frame = _push(self.name)
         self.start = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dur = time.perf_counter() - self._t0
+        if self._frame is not None:
+            _pop(self._frame)
         _current.reset(self._token)
         if exc_type is not None:
             self.attrs['error'] = exc_type.__name__
@@ -298,14 +426,18 @@ def phase_reset():
     with _lock:
         _seconds.clear()
         _counts.clear()
+        _gc['s'] = 0.0
+        _gc['n'] = 0
 
 
 def phase_snapshot():
     """{phase: {'s': seconds, 'n': calls}} accumulated since reset."""
     with _lock:
-        keys = set(_seconds) | set(_counts)
-        return {k: {'s': _seconds.get(k, 0.0), 'n': _counts.get(k, 0)}
-                for k in sorted(keys)}
+        seconds, counts = dict(_seconds), dict(_counts)
+    if _gc['n']:
+        seconds['runtime.gc'], counts['runtime.gc'] = _gc['s'], _gc['n']
+    return {k: {'s': seconds.get(k, 0.0), 'n': counts.get(k, 0)}
+            for k in sorted(set(seconds) | set(counts))}
 
 
 def phase_report():

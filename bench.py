@@ -266,9 +266,9 @@ def _current_mode():
 
 
 def _measure_mode(make_pool, payload, total_ops, label):
-    """Warmup + 3 timed runs + fallback counters + one synchronous
-    device-time pass for whatever execution mode the current env
-    resolves to.  Returns (median_rate, pool_from_last_run, stats)."""
+    """Warmup + 3 timed runs + fallback counters + one traced phase pass
+    for whatever execution mode the current env resolves to.  Returns
+    (median_rate, pool_from_last_run, stats)."""
     import gc
 
     from automerge_tpu import telemetry, trace
@@ -286,10 +286,6 @@ def _measure_mode(make_pool, payload, total_ops, label):
     # ---- timed runs ------------------------------------------------------
     times = []
     pool = None
-    # devtime's per-dispatch block_until_ready serializes the pipeline;
-    # an externally-exported AMTPU_DEVTIME=1 must not poison the timed
-    # runs (restored for the dedicated pass below)
-    devtime_prior = os.environ.pop('AMTPU_DEVTIME', None)
     # one measurement window per mode: flat metrics AND the registry
     # reset together, so the telemetry block captured below describes
     # exactly these 3 timed runs (not warmups, parity checks, or a
@@ -316,44 +312,10 @@ def _measure_mode(make_pool, payload, total_ops, label):
                  if k.startswith('fallback.')}
     print('[%s] fallbacks (3 runs): %s' % (label, fallbacks or 'none'),
           file=sys.stderr)
-    # captured HERE, before the devtime pass resets the flat metrics:
-    # the embedded block describes the timed runs, so a degraded run's
+    # captured HERE, before the phase pass resets the flat metrics: the
+    # embedded block describes the timed runs, so a degraded run's
     # fallback counts survive into the artifact
     telemetry_block = telemetry.bench_block()
-
-    # ---- device-time pass ------------------------------------------------
-    # One EXTRA pass with synchronous per-dispatch timing: every device
-    # dispatch blocks until ready, so kernel time is measured, not
-    # inferred.  Serializing the pipeline perturbs throughput, which is
-    # why this runs outside the timed runs.
-    trace.metrics_reset()
-    os.environ['AMTPU_DEVTIME'] = '1'
-    try:
-        dev_pool = make_pool()       # pool build outside the wall clock,
-        t0 = time.perf_counter()     # same as the timed runs
-        dev_pool.apply_batch_bytes(payload)
-        dev_wall = time.perf_counter() - t0
-    finally:
-        if devtime_prior is None:
-            os.environ.pop('AMTPU_DEVTIME', None)
-        else:
-            os.environ['AMTPU_DEVTIME'] = devtime_prior
-    m = trace.metrics_snapshot()
-    device = {
-        'sync_dispatch_s': round(m.get('device.dispatch_sync_s', 0.0), 4),
-        'dispatches': int(m.get('device.dispatches', 0)),
-        'sync_wall_s': round(dev_wall, 4),
-        'busy_frac': round(m.get('device.dispatch_sync_s', 0.0) /
-                           dev_wall, 4) if dev_wall else 0.0,
-    }
-    if m.get('resident.dispatches'):
-        device['resident_dispatches'] = int(m['resident.dispatches'])
-    print('[%s] device (sync pass): %.3fs kernels / %.3fs wall = %.1f%% '
-          'busy, %d dispatches' % (label, device['sync_dispatch_s'],
-                                   dev_wall, 100 * device['busy_frac'],
-                                   device['dispatches']), file=sys.stderr)
-    telemetry_block['device_s'] = device['sync_dispatch_s']
-    telemetry_block['device_dispatches'] = device['dispatches']
 
     # ---- phase pass ------------------------------------------------------
     # One extra TRACED run: per-phase seconds land in the BENCH line
@@ -383,7 +345,7 @@ def _measure_mode(make_pool, payload, total_ops, label):
     print('[%s] phase pass: %.2fs wall, device.collect share %.1f%%'
           % (label, ph_wall, 100 * telemetry_block['collect_share']),
           file=sys.stderr)
-    return rate, pool, {'fallbacks': fallbacks, 'device': device,
+    return rate, pool, {'fallbacks': fallbacks,
                         'telemetry': telemetry_block}
 
 
@@ -706,7 +668,7 @@ def run_multichip_child(dp):
     mesh pool mode (`make_pool` under AMTPU_MESH=dp, exported by the
     parent together with the matching device count) on the full
     `_measure_mode` protocol -- warmup, 3 fresh-pool timed steps,
-    device-time pass, TRACED phase pass."""
+    TRACED phase pass."""
     import jax
 
     from automerge_tpu.native import make_pool
@@ -715,7 +677,7 @@ def run_multichip_child(dp):
     if os.environ.get('AMTPU_MC_LIGHT'):
         # light re-measurement round (parent interleaves these across
         # the dp ladder to cancel host drift): warm + 3 timed steps,
-        # no device/phase passes
+        # no phase pass
         make_pool().apply_batch_bytes(payload)
         walls = []
         for _ in range(3):
@@ -740,7 +702,6 @@ def run_multichip_child(dp):
         'docs': n_docs, 'ops': total_ops,
         'step_wall_s': round(total_ops / rate, 4) if rate else 0.0,
         'fallbacks': stats['fallbacks'],
-        'device': stats['device'],
         'telemetry': stats['telemetry'],
     }
     print(json.dumps(result))
@@ -845,7 +806,7 @@ def run_multichip(args):
                   'force)' % (dropped, os.cpu_count() or 1, cap),
                   file=sys.stderr)
         dps = [d for d in dps if d <= cap]
-    # round 0: one FULL child per dp (device/phase passes, telemetry-
+    # round 0: one FULL child per dp (phase pass, telemetry-
     # rich line); rounds 1..R-1: LIGHT children interleaved across the
     # ladder so minute-scale host drift hits every dp equally.  The
     # line's headline value is the best round (noise on a shared box

@@ -62,7 +62,6 @@ _PATCHES = [
     (trace, 'add', _noop), (trace, 'metric', _noop),
     (telemetry, 'span', _null_span),
     (telemetry, 'observe_batch', _noop),
-    (telemetry, 'observe_device_dispatch', _noop),
     (telemetry, 'metric', _noop),
     # the always-on recorder/attribution seams (ISSUE 12): the raw arm
     # must approximate deleting them too, so the gate prices their
