@@ -23,6 +23,7 @@ import numpy as np
 
 from .. import faults, telemetry, trace
 from ..telemetry import attribution, recorder
+from ..utils import patch_map
 from ..utils.common import (doc_key, env_bool, env_int, env_raw, env_str,
                             parse_mesh_env)
 from ..utils.wire import map_header as _map_header
@@ -823,16 +824,27 @@ def _apply_batch_dicts(pool, changes_by_doc):
     pool's RESILIENT wire path (pool is any object with
     apply_batch_bytes_resilient) -- a device/native-path failure is
     retried, bisected, and at worst quarantined per doc instead of
-    failing every doc in the batch (automerge_tpu.resilience).  The
-    dict <-> msgpack work on either side of the pool call is the
-    `pool.repack` span."""
+    failing every doc in the batch (automerge_tpu.resilience).
+
+    Returns ``{doc_id: patch}`` in request order.  Inside
+    `patch_map.byte_results()` (the gateway's flush) that is a
+    `PatchMap` over the pool's result bytes, which decodes a doc only
+    when something reads it; every other caller gets decoded dicts.
+    The `pool.repack` span covers the request's `packb` before the pool
+    call and, after it, either the walk that finds each doc's span or
+    the whole `unpackb`, plus the `OPS` count."""
     with telemetry.span('pool.repack'):
         keyed = {NativeDocPool._doc_key(d): chs
                  for d, chs in changes_by_doc.items()}
         payload = msgpack.packb(keyed, use_bin_type=True)
     raw = pool.apply_batch_bytes_resilient(payload)
     with telemetry.span('pool.repack'):
-        out = msgpack.unpackb(raw, raw=False, strict_map_key=False)
+        if patch_map.wanted():
+            out = patch_map.PatchMap(raw, changes_by_doc)
+        else:
+            out = msgpack.unpackb(raw, raw=False, strict_map_key=False)
+            out = {d: out[NativeDocPool._doc_key(d)]
+                   for d in changes_by_doc}
         # the op counter lives here because this is where changes exist
         # as decoded dicts (the bytes path can't count ops without
         # paying a decode it otherwise avoids; docs it counts itself
@@ -843,7 +855,7 @@ def _apply_batch_dicts(pool, changes_by_doc):
         telemetry.OPS.inc(sum(len(c.get('ops', ()))
                               for chs in changes_by_doc.values()
                               for c in chs))
-        return {d: out[NativeDocPool._doc_key(d)] for d in changes_by_doc}
+        return out
 
 
 def _raise_if_quarantined(doc_id, result):

@@ -51,10 +51,10 @@ instead of growing memory; ``healthz`` gains a ``scheduler`` section
 Fan-out (ISSUE 9, docs/SERVING.md fan-out section): ``subscribe`` /
 ``unsubscribe`` / ``presence`` requests route through the same flush
 cycle (ordered against their doc's mutations), and every flush hands
-its per-doc post clocks + quarantine envelopes to the batched
-:class:`~automerge_tpu.sync.fanout.FanoutEngine`, which classifies all
-subscribers of all dirty docs in one vectorized (peer x doc) clock
--matrix pass and fans each doc's delta out encode-once.  Change->fanout
+the per-doc post clocks + quarantine envelopes of the docs it tracks to
+the batched :class:`~automerge_tpu.sync.fanout.FanoutEngine`, which
+classifies all subscribers of all dirty docs in one vectorized (peer x
+doc) clock-matrix pass and fans each doc's delta out encode-once.  Change->fanout
 latency is therefore bounded by the flush window; ``AMTPU_FANOUT=0``
 disables the engine (subscribe answers a typed error).
 """
@@ -72,6 +72,8 @@ from .. import faults, telemetry
 from ..resilience import is_quarantine_error, is_quarantined
 from ..telemetry import attribution, capacity
 from ..utils.common import env_bool
+from ..utils.patch_map import (DocResult, PatchMap, SubMap, byte_results,
+                               pack_body, plain)
 from .egress import EgressQueue
 from .queue import (READ_CMDS, AdmissionQueue,  # noqa: F401 (re-export)
                     Overloaded, PendingOp, flush_deadline_s,
@@ -193,16 +195,22 @@ class _Conn(object):
         writer, which tears the connection down itself."""
         if self.closed:
             return
+        n_raw = 0
         try:
             with telemetry.span('gateway.encode'):
                 if self.gateway.use_msgpack:
-                    import msgpack
-                    body = msgpack.packb(resp, use_bin_type=True)
-                    frame = struct.pack('>I', len(body)) + body
+                    # a flush's result views splice the pool's patch
+                    # bytes into the frame (utils/patch_map.py)
+                    parts, n_raw = pack_body(resp)
+                    size = sum(len(p) for p in parts)
+                    frame = b''.join([struct.pack('>I', size)] + parts)
                 else:
-                    frame = (json.dumps(resp) + '\n').encode()
+                    frame = (json.dumps(resp, default=plain)
+                             + '\n').encode()
         except (TypeError, ValueError):
             return
+        if n_raw:
+            telemetry.metric('scheduler.result_spliced_docs', n_raw)
         self.egress.stage(frame, kind='response')
 
     def _egress_overflow(self, _queue):
@@ -819,17 +827,22 @@ class GatewayServer(object):
                 # per-flush fan-out inputs: doc -> post clock /
                 # quarantine envelope / earliest admission time /
                 # originator (conn, submitted-clock) for echo
-                # suppression
+                # suppression; `results` holds the batch's (op, docs,
+                # result mapping) until the fan-out pass reads the docs
+                # it tracks
                 fan = {'updates': {}, 'quarantined': {}, 'enq': {},
-                       'origins': {}, 'traces': {}, 'patches': {}} \
+                       'origins': {}, 'traces': {}, 'patches': {},
+                       'results': []} \
                     if self.fanout is not None else None
-                if batch:
-                    self._run_batch(batch, fsp, fan)
+                out = self._run_batch(batch, fsp, fan) if batch else None
                 for op in execs:
                     self._run_exec(op, fan=fan)
                 if fan is not None:
                     fanout_s = self._fanout_flush(fan, fsp)
                     fanned = set(fan['updates']) | set(fan['quarantined'])
+                if isinstance(out, PatchMap) and out.n_decoded:
+                    telemetry.metric('scheduler.result_decoded_docs',
+                                     out.n_decoded)
                 if self.storage_tier is not None and touched:
                     self._storage_upkeep(batch, execs, touched)
         # attribution epilogue (responses are already on the wire;
@@ -984,7 +997,14 @@ class GatewayServer(object):
 
     def _run_batch(self, ops, fsp=None, fan=None):
         """One coalesced pool pass over disjoint-doc mutating ops, per
-        -request responses routed back by (conn, id)."""
+        -request responses routed back by (conn, id).  Returns the
+        pool's result mapping (None after a serial replay).
+
+        The pool call runs inside `byte_results`, so a native pool
+        answers with a `PatchMap`: each response is then a view over it
+        (`SubMap` in the request's doc order, keyed by the client's doc
+        ids, or one doc's `DocResult`) that the connection splices into
+        its frame without decoding a patch."""
         self._observe_wait(ops)
         telemetry.metric('scheduler.coalesced_ops', len(ops))
         for op in ops:
@@ -1007,7 +1027,8 @@ class GatewayServer(object):
                     merged.update(op.req['docs'])
             telemetry.BATCH_OCCUPANCY.observe(len(merged))
             telemetry.metric('scheduler.batched_docs', len(merged))
-            out = self.backend.pool.apply_batch(merged)
+            with byte_results():
+                out = self.backend.pool.apply_batch(merged)
         except Exception as e:
             attribution.flush_phases_end()
             # whole-batch protocol error (validation; nothing committed,
@@ -1019,7 +1040,7 @@ class GatewayServer(object):
             telemetry.metric('scheduler.serial_fallback')
             for op in ops:
                 self._run_exec(op, count=False, fan=fan)
-            return
+            return None
         dt = time.perf_counter() - t0
         # the collect share of the shared apply wall (zero when the
         # pool drove shard/mesh threads: their seams land in other
@@ -1037,26 +1058,32 @@ class GatewayServer(object):
         if self._sync_store is not None:
             self._sync_save(list(merged))
         flush_id = getattr(fsp, 'span_id', None)
+        spliced = isinstance(out, PatchMap)
+        bad = out.quarantined if spliced else \
+            {d for d in merged if is_quarantined(out[d])}
         for op in ops:
             if op.cmd == 'apply_changes':
-                res = out[op.req['doc']]
-                if is_quarantined(res):
+                doc = op.req['doc']
+                docs = (doc,)
+                if doc in bad:
                     telemetry.metric('scheduler.quarantined')
+                    res = out[doc]
                     resp = {'id': op.rid, 'error': res['error'],
                             'errorType': res['errorType']}
                 else:
-                    resp = {'id': op.rid, 'result': res}
-                if fan is not None:
-                    self._fan_note(fan, op, op.req['doc'], res)
+                    resp = {'id': op.rid,
+                            'result': DocResult(out, doc) if spliced
+                            else out[doc]}
             else:
-                sub = {d: out[d] for d in op.req['docs']}
-                nq = sum(1 for r in sub.values() if is_quarantined(r))
+                docs = tuple(op.req['docs'])
+                nq = sum(1 for d in docs if d in bad)
                 if nq:
                     telemetry.metric('scheduler.quarantined', nq)
-                resp = {'id': op.rid, 'result': sub}
-                if fan is not None:
-                    for d, r in sub.items():
-                        self._fan_note(fan, op, d, r)
+                resp = {'id': op.rid,
+                        'result': SubMap(out, docs) if spliced
+                        else {d: out[d] for d in docs}}
+            if fan is not None:
+                fan['results'].append((op, docs, out))
             # the per-command request series the serial server emits in
             # handle(): batched requests record the shared flush apply
             # time (docs/OBSERVABILITY.md)
@@ -1072,6 +1099,7 @@ class GatewayServer(object):
                     tctx.get('spanId'), cmd=op.cmd, rid=op.rid,
                     batched=True, flush=flush_id):
                 self._finish(op, resp)
+        return out
 
     def _run_exec(self, op, count=True, fan=None):
         """One ordered singleton through the serial backend dispatch --
@@ -1420,6 +1448,18 @@ class GatewayServer(object):
         return {'restored': restored, 'failed': failed,
                 'bytes': nbytes}
 
+    def _note_batch_results(self, fan):
+        """Notes the flush's batched results for fan-out, reading only
+        the docs the engine tracks (`FanoutEngine.tracked`): it skips
+        every other doc, whose result stays the pool's bytes."""
+        results = fan.pop('results')
+        tracked = self.fanout.tracked(
+            [d for _op, docs, _out in results for d in docs])
+        for op, docs, out in results:
+            for d in docs:
+                if d in tracked:
+                    self._fan_note(fan, op, d, out[d])
+
     def _fanout_flush(self, fan, fsp):
         """Hands the flush's committed docs to the fan-out engine; the
         span nests under scheduler.flush (contextvars) and carries the
@@ -1427,6 +1467,7 @@ class GatewayServer(object):
         the pass's wall seconds (the `fanout` attribution stage)."""
         t0 = time.perf_counter()
         try:
+            self._note_batch_results(fan)
             with telemetry.span('sync.fanout', docs=len(fan['updates']),
                                 flush=getattr(fsp, 'span_id', None)):
                 self.fanout.on_flush(fan['updates'],

@@ -520,6 +520,20 @@ class FanoutEngine(object):
 
     # -- the batched flush pass ----------------------------------------
 
+    def tracked(self, doc_ids):
+        """The docs among `doc_ids` a flush pass would look at: those
+        with a doc row, a subscription or staged presence, and those a
+        prefix subscription matches.  `on_flush` skips every other doc,
+        so a caller need not read their results at all."""
+        with self._lock:
+            prefixes = tuple({p for ps in self._prefix_subs.values()
+                              for p in ps})
+            return {d for d in doc_ids
+                    if d in self._doc_row or d in self._doc_subs
+                    or d in self._presence
+                    or (prefixes and isinstance(d, str)
+                        and d.startswith(prefixes))}
+
     def on_flush(self, updates, quarantined=None, enq=None,
                  origins=None, traces=None, patches=None):
         """One fan-out pass for one gateway flush.

@@ -223,9 +223,14 @@ KNOWN_RESILIENCE_KEYS = ('retry.attempts', 'retry.success',
 #                      protocol error (per-request results restored)
 # quarantined        per-doc resilience envelopes routed back to the
 #                      originating request by a flush
+# result_spliced_docs  per-doc results a response carried as the pool's
+#                      own bytes (utils/patch_map.py)
+# result_decoded_docs  per-doc flush results something decoded (fan-out
+#                      of a tracked doc, JSONL framing, a test's read)
 KNOWN_SCHEDULER_KEYS = ('flushes', 'coalesced_ops', 'batched_docs',
                         'exec_ops', 'bypass_reads', 'parked', 'shed',
-                        'serial_fallback', 'quarantined')
+                        'serial_fallback', 'quarantined',
+                        'result_spliced_docs', 'result_decoded_docs')
 
 # batched sync fan-out counters (`telemetry.metric('sync.fanout.<name>')`
 # call sites in sync/fanout.py + scheduler/gateway.py; glossary:
