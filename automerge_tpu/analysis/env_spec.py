@@ -119,8 +119,11 @@ ENV_FLAGS = (
     EnvFlag('AMTPU_TRACE_BEGIN', 'raw', None, False,
             'native/core.cpp (per-begin debug trace)'),
     # -- mesh ---------------------------------------------------------------
+    # overrides the layout the chips decide (native.make_pool: dp = the
+    # chips of a multi-chip TPU process); a test seam, not a switch
     EnvFlag('AMTPU_MESH', 'special', None, True,
-            'utils/common.py parse_mesh_env (factory + fence + guard)'),
+            'utils/common.py parse_mesh_env (factory override + fence + '
+            'guard)'),
     EnvFlag('AMTPU_MESH_SP_MIN', 'int', 131072, False,
             'native/resident.py (default SP_CROSSOVER_ELEMS)'),
     EnvFlag('AMTPU_MESH_CONNECT_DEADLINE_S', 'float', 60, False,

@@ -15,6 +15,7 @@ import ctypes
 import os
 import re
 import subprocess
+import sys
 import threading
 import time
 
@@ -3149,18 +3150,107 @@ def _watch_compiles():
     jax.monitoring.register_event_duration_secs_listener(on_duration)
 
 
-def make_pool():
-    """The execution-mode-aware pool factory (ISSUE 7): `MeshDocPool`
-    when ``AMTPU_MESH=dp[,sp]`` requests mesh execution, else a plain
-    `NativeDocPool`.  The sidecar backend and the CI gates construct
-    through this, so flipping one env var moves a whole serving stack
-    (gateway, resilience, sidecar) onto the device mesh unchanged."""
-    _watch_compiles()
-    mesh = parse_mesh_env()
-    if mesh is None:
+def _count_devices():
+    """(platform, device kind, count) of the devices this process holds;
+    starts JAX's backend if nothing has yet."""
+    import jax
+    devs = jax.devices()
+    return devs[0].platform, devs[0].device_kind, len(devs)
+
+
+def _devices_held():
+    """`_count_devices()`, or None where counting would start a backend
+    that may take a TPU: none is up yet and JAX may use more than the
+    CPU.  A process that has not touched JAX keeps its chips free."""
+    import jax
+    from jax._src import xla_bridge
+    if (not xla_bridge.backends_are_initialized()
+            and jax.config.jax_platforms != 'cpu'):
+        return None
+    return _count_devices()
+
+
+def _layout_for(held):
+    """dp of the pool for `held` devices: every chip of a multi-chip TPU
+    process, else 1 (one chip, or a CPU backend whatever its virtual
+    devices: the CPU runs the full host path, where chips buy nothing)."""
+    platform, _kind, count = held
+    return count if platform == 'tpu' and count > 1 else 1
+
+
+_layouts_stated = set()
+
+
+def _state_layout(pool_class, dp, held):
+    """Says once per process and layout, on stderr, which pool serves
+    and on what: pool class, dp, device kind and count; adds dp to the
+    ``pool.chips`` counter."""
+    if (pool_class, dp) in _layouts_stated:
+        return
+    _layouts_stated.add((pool_class, dp))
+    telemetry.metric('pool.chips', dp)
+    platform, kind, count = held
+    print('[pool] %s dp=%d on %d x %s (%s)'
+          % (pool_class, dp, count, kind, platform),
+          file=sys.stderr, flush=True)
+
+
+def _pool_for(held):
+    dp = _layout_for(held)
+    if dp == 1:
+        _state_layout('NativeDocPool', 1, held)
         return NativeDocPool()
     from .mesh_pool import MeshDocPool
-    return MeshDocPool(dp=mesh[0], sp=mesh[1])
+    return MeshDocPool(dp=dp)      # states its layout at first use
+
+
+class _PoolAtFirstUse(object):
+    """`make_pool`'s pool in a process with no JAX backend up yet: the
+    chips are counted, and the pool built, at the first attribute
+    anything reads or sets; from then on every attribute is that
+    pool's."""
+
+    def __init__(self):
+        object.__setattr__(self, '_built', None)
+        object.__setattr__(self, '_build_lock', threading.Lock())
+
+    def _pool_now(self):
+        with self._build_lock:
+            if self._built is None:
+                object.__setattr__(self, '_built',
+                                   _pool_for(_count_devices()))
+        return self._built
+
+    def __getattr__(self, name):
+        if name in ('_built', '_build_lock'):   # a copy made without init
+            raise AttributeError(name)
+        return getattr(self._pool_now(), name)
+
+    def __setattr__(self, name, value):
+        setattr(self._pool_now(), name, value)
+
+
+def make_pool():
+    """The pool for the chips this process holds: `MeshDocPool(dp=n)`
+    on a TPU process with n > 1 chips (docs split by the FNV doc hash,
+    one chip pool and host thread per chip), else a plain
+    `NativeDocPool`.  In a process that has not started JAX yet the
+    chips are counted at the pool's first use, so building a pool never
+    takes the chips.  ``AMTPU_MESH=dp[,sp]`` overrides the layout the
+    chips decide (``0`` forces the single pool); it is kept as a test
+    seam.  The sidecar backend, the gateway's benchmark and the CI
+    gates construct through this."""
+    _watch_compiles()
+    if env_raw('AMTPU_MESH') is not None:
+        mesh = parse_mesh_env()
+        if mesh is None:
+            return NativeDocPool()
+        from .mesh_pool import MeshDocPool
+        return MeshDocPool(dp=mesh[0], sp=mesh[1])
+    held = _devices_held()
+    if held is None:
+        return _PoolAtFirstUse()
+    return _pool_for(held)
 
 
 def __getattr__(name):

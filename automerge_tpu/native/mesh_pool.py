@@ -10,8 +10,9 @@ pinned to ONE mesh device via `jax.default_device` (thread-local, so
 concurrent chips never fight over placement).  The pool speaks the
 same `apply_batch`/`apply_batch_bytes` + patches contract as
 `NativeDocPool`, so the scheduler gateway, the resilience
-retry/bisect/quarantine path, and the sidecar serve it unchanged
-(select with ``AMTPU_MESH=dp[,sp]`` through `native.make_pool`).
+retry/bisect/quarantine path, and the sidecar serve it unchanged.
+`native.make_pool` builds it with dp = every chip of a multi-chip TPU
+process (``AMTPU_MESH=dp[,sp]`` overrides, as a test seam).
 
 The dryrun's scaling losses are attacked structurally:
 
@@ -54,7 +55,8 @@ from .. import trace
 from ..utils.common import env_raw, parse_mesh_env
 from ..utils.jaxenv import ensure_cpu_devices
 from . import (NativeDocPool, ShardedNativePool, _ctx_pending_arrays,
-               _ctx_ready, _run_phase_b_entry, _read_map_header, lib)
+               _ctx_ready, _run_phase_b_entry, _read_map_header,
+               _state_layout, lib)
 
 
 class MeshChipPool(NativeDocPool):
@@ -187,6 +189,8 @@ class MeshDocPool(ShardedNativePool):
             # (kernel placement) is the first -- the rest belong to the
             # chip's sp sub-mesh when the sp fence routes a long list
             self._devices = [devs[s * self.sp] for s in range(self.dp)]
+            _state_layout('MeshDocPool', self.dp, (
+                devs[0].platform, devs[0].device_kind, len(devs)))
         return self._devices
 
     @property
@@ -243,23 +247,26 @@ class MeshDocPool(ShardedNativePool):
                 errors.append((s, e))
 
         def chip(s):
-            try:
-                t0 = time.perf_counter()
-                ctx = pools[s]._phase_a(subs[s])
-                t_a[s] = time.perf_counter() - t0
-            except Exception as e:
-                with cv:
-                    errors.append((s, e))
-                    state['outstanding'] -= 1
-                    cv.notify_all()
-            else:
-                with cv:
-                    produced.append((s, pools[s], ctx))
-                    state['outstanding'] -= 1
-                    cv.notify_all()
-            while _collect_one_ready_first(produced, state, cv, keep,
-                                           err):
-                pass
+            # one span per chip thread: its phase a and the collects it
+            # takes from the shared collector
+            with trace.span('mesh.chip'):
+                try:
+                    t0 = time.perf_counter()
+                    ctx = pools[s]._phase_a(subs[s])
+                    t_a[s] = time.perf_counter() - t0
+                except Exception as e:
+                    with cv:
+                        errors.append((s, e))
+                        state['outstanding'] -= 1
+                        cv.notify_all()
+                else:
+                    with cv:
+                        produced.append((s, pools[s], ctx))
+                        state['outstanding'] -= 1
+                        cv.notify_all()
+                while _collect_one_ready_first(produced, state, cv, keep,
+                                               err):
+                    pass
 
         if len(live) <= 1:
             for s in live:

@@ -104,9 +104,9 @@ class SidecarBackend:
 
     def __init__(self, pool=None):
         if pool is None:
-            # AMTPU_MESH=dp[,sp] moves the whole serving stack (gateway
-            # coalescing, resilience, this sidecar) onto the device
-            # mesh; default stays the single-device pool
+            # the pool for the chips this process holds: the device
+            # mesh on a multi-chip TPU host, else the single-device
+            # pool (native.make_pool)
             from ..native import make_pool
             pool = make_pool()
         self.pool = pool
