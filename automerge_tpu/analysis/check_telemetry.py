@@ -46,6 +46,7 @@ PRESEED_BLOCKS = {
     'mesh': 'KNOWN_MESH_KEYS',
     'resilience': 'KNOWN_RESILIENCE_KEYS',
     'scheduler': 'KNOWN_SCHEDULER_KEYS',
+    'gateway': 'KNOWN_GATEWAY_KEYS',
     'sync.fanout': 'KNOWN_FANOUT_KEYS',
     'egress': 'KNOWN_EGRESS_KEYS',
     'storage': 'KNOWN_STORAGE_KEYS',
@@ -85,7 +86,7 @@ DYNAMIC_KEY_PATTERNS = (
 DOC_NAMESPACES = tuple(sorted({ns.split('.')[0]
                                for ns in PRESEED_BLOCKS})) + (
     'sched', 'sidecar', 'device', 'host', 'hostfull', 'hostreg',
-    'sanitize', 'pallas', 'ops', 'transfer', 'jit', 'gateway', 'pool')
+    'sanitize', 'pallas', 'ops', 'transfer', 'jit', 'pool')
 
 _TOKEN_RE = re.compile(r'`([A-Za-z0-9_./*%\[\]]+)`')
 _KEY_RE = re.compile(r'^[a-z][a-z0-9_]*(\.[a-zA-Z0-9_.*]+)+$')

@@ -240,6 +240,10 @@ def _load():
     lib.amtpu_shard_buf.argtypes = [ctypes.c_void_p, ctypes.c_int,
                                     ctypes.POINTER(ctypes.c_int64)]
     lib.amtpu_shard_free.argtypes = [ctypes.c_void_p]
+    lib.amtpu_scan_changes.restype = None
+    lib.amtpu_scan_changes.argtypes = [
+        ctypes.c_char_p, ctypes.c_int64, ctypes.POINTER(ctypes.c_int64),
+        ctypes.c_int64, ctypes.POINTER(ctypes.c_int64)]
     return lib
 
 
@@ -292,6 +296,21 @@ def columnar_encode_native(raws):
     if not ptr:
         _raise_last()
     return _take_buf(ptr, out_len.value), int(stats[0]), int(stats[1])
+
+
+def scan_changes(buf, spans):
+    """Op counts of the change arrays at `spans`, a list of (start,
+    end) offsets into the bytes `buf`: -1 for a span that is not
+    already in the form ``packb(unpackb(span))`` gives, or whose ops
+    the count cannot read (core.cpp `amtpu_scan_changes`).  The C++
+    walk runs without the interpreter lock."""
+    pairs = np.ascontiguousarray(spans, dtype=np.int64).reshape(-1)
+    ops = np.empty(len(spans), np.int64)
+    lib().amtpu_scan_changes(
+        buf, len(buf),
+        pairs.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)), len(spans),
+        ops.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
+    return ops.tolist()
 
 
 def columnar_decode_native(blob):
@@ -831,13 +850,21 @@ def _apply_batch_dicts(pool, changes_by_doc):
     `patch_map.byte_results()` (the gateway's flush) that is a
     `PatchMap` over the pool's result bytes, which decodes a doc only
     when something reads it; every other caller gets decoded dicts.
-    The `pool.repack` span covers the request's `packb` before the pool
-    call and, after it, either the walk that finds each doc's span or
-    the whole `unpackb`, plus the `OPS` count."""
+    A mapping with a ``packed()`` method (the gateway's merged requests,
+    `utils.request_map.BatchDocs`) hands over its own payload and op
+    count, its frames' change bytes spliced in.  The `pool.repack` span
+    covers the payload before the pool call and, after it, either the
+    walk that finds each doc's span or the whole `unpackb`, plus the
+    `OPS` count."""
     with telemetry.span('pool.repack'):
-        keyed = {NativeDocPool._doc_key(d): chs
-                 for d, chs in changes_by_doc.items()}
-        payload = msgpack.packb(keyed, use_bin_type=True)
+        packed = getattr(changes_by_doc, 'packed', None)
+        if packed is not None:
+            payload, n_ops = packed()
+        else:
+            keyed = {NativeDocPool._doc_key(d): chs
+                     for d, chs in changes_by_doc.items()}
+            payload = msgpack.packb(keyed, use_bin_type=True)
+            n_ops = None
     raw = pool.apply_batch_bytes_resilient(payload)
     with telemetry.span('pool.repack'):
         if patch_map.wanted():
@@ -846,16 +873,15 @@ def _apply_batch_dicts(pool, changes_by_doc):
             out = msgpack.unpackb(raw, raw=False, strict_map_key=False)
             out = {d: out[NativeDocPool._doc_key(d)]
                    for d in changes_by_doc}
-        # the op counter lives here because this is where changes exist
-        # as decoded dicts (the bytes path can't count ops without
-        # paying a decode it otherwise avoids; docs it counts itself
-        # from the map header), and AFTER the apply so a failed batch
-        # doesn't inflate it; counts submitted ops of committed batches
-        # -- duplicates/queued changes included (the engine path counts
-        # exact causally-applied ops)
-        telemetry.OPS.inc(sum(len(c.get('ops', ()))
-                              for chs in changes_by_doc.values()
-                              for c in chs))
+        # AFTER the apply so a failed batch doesn't inflate it; counts
+        # submitted ops of committed batches -- duplicates/queued
+        # changes included (the engine path counts exact causally-
+        # applied ops).  A packed batch counted its spliced docs' ops
+        # in the reader's native scan, so it decodes nothing here
+        if n_ops is None:
+            n_ops = sum(len(c.get('ops', ()))
+                        for chs in changes_by_doc.values() for c in chs)
+        telemetry.OPS.inc(n_ops)
         return out
 
 

@@ -74,6 +74,7 @@ from ..telemetry import attribution, capacity
 from ..utils.common import env_bool
 from ..utils.patch_map import (DocResult, PatchMap, SubMap, byte_results,
                                pack_body, plain)
+from ..utils.request_map import BatchDocs, FrameDocs, read_request
 from .egress import EgressQueue
 from .queue import (READ_CMDS, AdmissionQueue,  # noqa: F401 (re-export)
                     Overloaded, PendingOp, flush_deadline_s,
@@ -110,13 +111,18 @@ ROUTER_CMDS = ('migrate_out', 'migrate_in')
 
 def _op_weight(cmd, req):
     """Queued-op count a request admits as (the admission unit): number
-    of changes for the apply commands, 1 for everything else."""
+    of changes for the apply commands, 1 for everything else.  A
+    frame's docs count from their array headers, undecoded."""
     try:
         if cmd == 'apply_changes':
             return max(1, len(req['changes']))
         if cmd == 'apply_batch':
+            docs = req['docs']
+            if isinstance(docs, FrameDocs):
+                return max(1, sum(max(1, docs.n_changes(d))
+                                  for d in docs))
             return max(1, sum(max(1, len(chs))
-                              for chs in req['docs'].values()))
+                              for chs in docs.values()))
     except (TypeError, AttributeError, KeyError):
         pass
     return 1
@@ -131,6 +137,8 @@ def _op_docs(cmd, req):
     flush into whole-InternalError."""
     if cmd == 'apply_batch':
         docs = req.get('docs')
+        if isinstance(docs, FrameDocs):
+            return tuple(docs)      # non-empty, every value an array
         if not isinstance(docs, dict) or not docs:
             return None
         if any(not isinstance(chs, list) for chs in docs.values()):
@@ -270,7 +278,7 @@ class _Conn(object):
                 inline()
 
     def _run_msgpack(self):
-        import msgpack
+        from ..native import scan_changes
         while True:
             head = self.rfile.read(4)
             if len(head) < 4:
@@ -282,8 +290,9 @@ class _Conn(object):
             t0 = time.perf_counter()    # frame receipt (see _run_jsonl)
             with telemetry.span('gateway.decode'):
                 try:
-                    req = msgpack.unpackb(body, raw=False,
-                                          strict_map_key=False)
+                    # an apply_batch keeps its changes as the frame's
+                    # bytes (utils/request_map.py)
+                    req = read_request(body, scan_changes)
                     if not isinstance(req, dict):
                         raise ValueError('request is not a map')
                 except Exception as e:
@@ -1014,15 +1023,15 @@ class GatewayServer(object):
         # seams can split the shared apply wall into dispatch/collect
         attribution.flush_phases_begin()
         t0 = time.perf_counter()
+        merged = BatchDocs()
         try:
             # merge building sits INSIDE the try: a request malformed in
             # a way routing didn't catch degrades to the serial replay
             # (per-request protocol errors), never to a whole-flush
             # InternalError
-            merged = {}
             for op in ops:
                 if op.cmd == 'apply_changes':
-                    merged[op.req['doc']] = op.req['changes']
+                    merged.add(op.req['doc'], op.req['changes'])
                 else:                       # apply_batch
                     merged.update(op.req['docs'])
             telemetry.BATCH_OCCUPANCY.observe(len(merged))
@@ -1039,8 +1048,18 @@ class GatewayServer(object):
                 raise
             telemetry.metric('scheduler.serial_fallback')
             for op in ops:
+                docs = op.req.get('docs')
+                if isinstance(docs, FrameDocs):
+                    # the serial backend takes dicts: decode the frame
+                    op.req = dict(op.req, docs=dict(docs))
                 self._run_exec(op, count=False, fan=fan)
             return None
+        finally:
+            # the pool's payload carried these docs as their frames'
+            # bytes (utils/request_map.py), a refused one included
+            if merged.n_spliced:
+                telemetry.metric('gateway.request_spliced_docs',
+                                 merged.n_spliced)
         dt = time.perf_counter() - t0
         # the collect share of the shared apply wall (zero when the
         # pool drove shard/mesh threads: their seams land in other

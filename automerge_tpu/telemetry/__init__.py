@@ -232,6 +232,17 @@ KNOWN_SCHEDULER_KEYS = ('flushes', 'coalesced_ops', 'batched_docs',
                         'serial_fallback', 'quarantined',
                         'result_spliced_docs', 'result_decoded_docs')
 
+# gateway request-path counters (`telemetry.metric('gateway.<name>')`
+# call sites in scheduler/gateway.py and utils/request_map.py; glossary:
+# docs/OBSERVABILITY.md, architecture: docs/SERVING.md request path),
+# pre-seeded into every bench_block:
+# request_spliced_docs  apply_batch docs whose changes reached the
+#                         pool's payload as their frame's own bytes
+# request_decoded_docs  frame docs whose changes something decoded (a
+#                         span not in canonical form, a serial replay,
+#                         fan-out of a tracked doc)
+KNOWN_GATEWAY_KEYS = ('request_spliced_docs', 'request_decoded_docs')
+
 # batched sync fan-out counters (`telemetry.metric('sync.fanout.<name>')`
 # call sites in sync/fanout.py + scheduler/gateway.py; glossary:
 # docs/OBSERVABILITY.md, architecture: docs/SERVING.md), pre-seeded into
@@ -798,6 +809,10 @@ def bench_block():
     scheduler.update({k.split('.', 1)[1]: round(v, 6)
                       for k, v in flat.items()
                       if k.startswith('scheduler.')})
+    gateway = {r: 0.0 for r in KNOWN_GATEWAY_KEYS}
+    gateway.update({k.split('.', 1)[1]: round(v, 6)
+                    for k, v in flat.items()
+                    if k.startswith('gateway.')})
     resident = {r: 0.0 for r in KNOWN_RESIDENT_BATCH_KEYS}
     resident.update({k.split('.', 1)[1]: round(v, 6)
                      for k, v in flat.items()
@@ -864,6 +879,7 @@ def bench_block():
         'collect': collect,
         'resilience': resilience,
         'scheduler': scheduler,
+        'gateway': gateway,
         'resident': resident,
         'pipeline': pipeline,
         'mesh': mesh,

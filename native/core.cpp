@@ -4034,32 +4034,17 @@ static bool utf8_valid(const u8* s, size_t n) {
 }
 
 // ---------------------------------------------------------------------------
-// canonical re-encoder: walks one msgpack value and emits the canonical
-// form msgpack-python's packb(unpackb(raw)) would produce.  Returns
-// false (without a defined writer state) for anything outside the
-// conservative canonical subset: ext types, non-string or duplicate map
-// keys, invalid utf-8, nesting past the depth cap.  float32 values
-// re-encode as float64 (what Python unpack->pack does), so their bytes
-// differ from the input and the compare in canonical_ok sends the
-// change residual -- exactly the Python behavior.
+// canonical form: the bytes msgpack-python's packb(unpackb(raw)) gives.
+// A value is checked in place; anything outside the conservative
+// canonical subset (ext types, non-string or duplicate map keys, invalid
+// utf-8, float32, a header longer than its length or value needs,
+// nesting past the depth cap) is not canonical.
 // ---------------------------------------------------------------------------
 
 static const int CANON_MAX_DEPTH = 192;
 
 // parsed int as (neg, mag): mag is |v| for neg, v for non-neg
 struct IntVal { bool neg; u64 mag; };
-
-static void put_canon_int(Writer& w, const IntVal& v) {
-  if (!v.neg) {
-    w.uinteger(v.mag);
-  } else {
-    // mag <= 2^63 by wire construction
-    w.integer(-static_cast<i64>(v.mag - 1) - 1);
-  }
-}
-
-static bool canon_value(const u8*& p, const u8* end, Writer& w,
-                        int depth);
 
 static bool canon_read_uint(const u8*& p, const u8* end, size_t width,
                             u64* out) {
@@ -4106,136 +4091,128 @@ static bool canon_read_int(const u8*& p, const u8* end, IntVal* out) {
   }
 }
 
-// str header; false when not a str tag
-static bool canon_read_strhdr(const u8*& p, const u8* end, size_t* n) {
-  if (p >= end) return false;
-  u8 b = *p++;
-  u64 v;
-  if ((b & 0xe0) == 0xa0) { *n = b & 0x1f; return true; }
-  if (b == 0xd9) { if (!canon_read_uint(p, end, 1, &v)) return false;
-                   *n = v; return true; }
-  if (b == 0xda) { if (!canon_read_uint(p, end, 2, &v)) return false;
-                   *n = v; return true; }
-  if (b == 0xdb) { if (!canon_read_uint(p, end, 4, &v)) return false;
-                   *n = v; return true; }
-  --p;
-  return false;
+static inline bool is_array_tag(u8 b) {
+  return (b & 0xf0) == 0x90 || b == 0xdc || b == 0xdd;
+}
+static inline bool is_map_tag(u8 b) {
+  return (b & 0xf0) == 0x80 || b == 0xde || b == 0xdf;
+}
+// a str's bytes, valid utf-8; the ascii run is checked inline
+static inline bool canon_str_body(const u8*& p, const u8* end, size_t n) {
+  if (static_cast<size_t>(end - p) < n) return false;
+  for (size_t i = 0; i < n; ++i)
+    if (p[i] & 0x80) {
+      if (!utf8_valid(p + i, n - i)) return false;
+      break;
+    }
+  p += n;
+  return true;
 }
 
-static bool canon_value(const u8*& p, const u8* end, Writer& w,
-                        int depth) {
+// true iff the value at p already is its canonical form: every header
+// the shortest for its length or value, no float32 (packb(unpackb())
+// widens it to float64) or ext, valid utf-8, unique string map keys,
+// nesting within the depth cap.  Advances p past the value.  The
+// one-byte tags come first: they are nearly every value of a change.
+// With `ops`, the value must also be an array of maps (one doc's
+// changes), and *ops gains the length of each map's 'ops' array, which
+// must be an array where present.
+static bool canon_asis(const u8*& p, const u8* end, int depth,
+                       int64_t* ops = nullptr) {
   if (depth > CANON_MAX_DEPTH || p >= end) return false;
-  u8 b = *p;
-  // int family
-  if (b <= 0x7f || b >= 0xe0 || (b >= 0xcc && b <= 0xd3)) {
-    IntVal v;
-    if (!canon_read_int(p, end, &v)) return false;
-    put_canon_int(w, v);
+  u8 b = *p++;
+  if (ops && !(depth == 0 ? is_array_tag(b) : is_map_tag(b))) return false;
+  if (b <= 0x7f || b >= 0xe0) return true;
+  if (b >= 0xa0 && b <= 0xbf) return canon_str_body(p, end, b & 0x1f);
+  u64 v;
+  bool is_map = false;
+  if (b <= 0x9f) {                       // fixmap / fixarray
+    is_map = b <= 0x8f;
+    v = b & 0x0f;
+  } else if (b == 0xdc || b == 0xdd || b == 0xde || b == 0xdf) {
+    is_map = b >= 0xde;
+    if (!canon_read_uint(p, end, (b & 1) ? 4 : 2, &v)) return false;
+    if (v <= ((b & 1) ? 0xffff : 15)) return false;
+  } else if (b >= 0xcc && b <= 0xd3) {
+    size_t width = size_t(1) << ((b - 0xcc) & 3);
+    if (!canon_read_uint(p, end, width, &v)) return false;
+    static const u64 kUnsignedMin[4] = {0x80, 0x100, 0x10000,
+                                        0x100000000ULL};
+    if (b <= 0xcf) return v >= kUnsignedMin[b - 0xcc];
+    // signed tags are canonical only below the next narrower range
+    static const i64 kSignedMax[4] = {-33, -129, -32769, -2147483649LL};
+    i64 sv;
+    if (b == 0xd0) sv = static_cast<int8_t>(v);
+    else if (b == 0xd1) sv = static_cast<int16_t>(v);
+    else if (b == 0xd2) sv = static_cast<int32_t>(v);
+    else sv = static_cast<i64>(v);
+    return sv <= kSignedMax[b - 0xd0];
+  } else {
+    switch (b) {
+      case 0xc0: case 0xc2: case 0xc3: return true;
+      case 0xcb:
+        if (end - p < 8) return false;
+        p += 8;
+        return true;
+      case 0xd9: case 0xda: case 0xdb:
+        if (!canon_read_uint(p, end, size_t(1) << (b - 0xd9), &v))
+          return false;
+        if (v <= (b == 0xd9 ? 31 : b == 0xda ? 0xff : 0xffff))
+          return false;
+        return canon_str_body(p, end, v);
+      case 0xc4: case 0xc5: case 0xc6:
+        if (!canon_read_uint(p, end, size_t(1) << (b - 0xc4), &v))
+          return false;
+        if ((b == 0xc5 && v <= 0xff) || (b == 0xc6 && v <= 0xffff))
+          return false;
+        if (static_cast<u64>(end - p) < v) return false;
+        p += v;
+        return true;
+      default:
+        return false;  // float32, ext, reserved
+    }
+  }
+  if (!is_map) {
+    for (u64 i = 0; i < v; ++i)
+      if (!canon_asis(p, end, depth + 1, ops)) return false;
     return true;
   }
-  // str family
-  if ((b & 0xe0) == 0xa0 || b == 0xd9 || b == 0xda || b == 0xdb) {
-    size_t n;
-    if (!canon_read_strhdr(p, end, &n)) return false;
-    if (static_cast<size_t>(end - p) < n) return false;
-    if (!utf8_valid(p, n)) return false;
-    w.str(reinterpret_cast<const char*>(p), n);
-    p += n;
-    return true;
-  }
-  switch (b) {
-    case 0xc0: ++p; w.nil(); return true;
-    case 0xc2: ++p; w.boolean(false); return true;
-    case 0xc3: ++p; w.boolean(true); return true;
-    case 0xca: {  // float32 -> canonical float64 (bytes will differ)
-      ++p;
-      u64 v;
-      if (!canon_read_uint(p, end, 4, &v)) return false;
-      u32 bits = static_cast<u32>(v);
-      float f;
-      std::memcpy(&f, &bits, 4);
-      w.real(static_cast<double>(f));
-      return true;
-    }
-    case 0xcb: {  // float64: bit-verbatim copy (preserves NaN payloads)
-      if (static_cast<size_t>(end - p) < 9) return false;
-      w.raw(p, 9);
-      p += 9;
-      return true;
-    }
-    case 0xc4: case 0xc5: case 0xc6: {  // bin
-      ++p;
-      u64 n;
-      if (!canon_read_uint(p, end, size_t(1) << (b - 0xc4), &n))
+  const u8* small[16];
+  std::vector<const u8*> big;
+  for (u64 i = 0; i < v; ++i) {
+    const u8* key = p;
+    if (p >= end || !((*p & 0xe0) == 0xa0 || *p == 0xd9 || *p == 0xda ||
+                      *p == 0xdb))
+      return false;
+    if (!canon_asis(p, end, depth + 1)) return false;
+    // a key's header holds its length, so equal leading bytes are an
+    // equal key; an earlier key lies before this one, so the compare
+    // stays inside the span
+    size_t kn = static_cast<size_t>(p - key);
+    if (i == 16) big.assign(small, small + 16);
+    const u8** seen = i < 16 ? small : big.data();
+    for (u64 j = 0; j < i; ++j)
+      if (std::memcmp(seen[j], key, kn) == 0) return false;
+    if (i < 16) small[i] = key;
+    else big.push_back(key);
+    if (ops && kn == 4 && std::memcmp(key, "\xa3ops", 4) == 0) {
+      if (p >= end || !is_array_tag(*p)) return false;
+      u64 n_ops = *p & 0x0f;
+      const u8* h = p + 1;
+      if (*p >= 0xdc && !canon_read_uint(h, end, *p == 0xdc ? 2 : 4, &n_ops))
         return false;
-      if (static_cast<size_t>(end - p) < n) return false;
-      if (n <= 0xff) { w.buf.push_back(0xc4); w.buf.push_back(u8(n)); }
-      else if (n <= 0xffff) {
-        w.buf.push_back(0xc5);
-        w.buf.push_back(u8(n >> 8));
-        w.buf.push_back(u8(n & 0xff));
-      } else {
-        w.buf.push_back(0xc6);
-        for (int i = 3; i >= 0; --i)
-          w.buf.push_back(u8((n >> (8 * i)) & 0xff));
-      }
-      w.raw(p, n);
-      p += n;
-      return true;
+      *ops += static_cast<int64_t>(n_ops);
     }
-    default: break;
+    if (!canon_asis(p, end, depth + 1)) return false;
   }
-  if ((b & 0xf0) == 0x90 || b == 0xdc || b == 0xdd) {  // array
-    ++p;
-    u64 n;
-    if ((b & 0xf0) == 0x90) n = b & 0x0f;
-    else if (!canon_read_uint(p, end, b == 0xdc ? 2 : 4, &n))
-      return false;
-    w.array(n);
-    for (u64 i = 0; i < n; ++i)
-      if (!canon_value(p, end, w, depth + 1)) return false;
-    return true;
-  }
-  if ((b & 0xf0) == 0x80 || b == 0xde || b == 0xdf) {  // map
-    ++p;
-    u64 n;
-    if ((b & 0xf0) == 0x80) n = b & 0x0f;
-    else if (!canon_read_uint(p, end, b == 0xde ? 2 : 4, &n))
-      return false;
-    w.map(n);
-    // conservative: keys must be unique STRINGS (a duplicate or
-    // non-string key would collapse/reorder through Python's dict and
-    // break cross-codec decode parity)
-    std::vector<std::string_view> keys;
-    keys.reserve(n < 64 ? n : 64);
-    for (u64 i = 0; i < n; ++i) {
-      size_t kn;
-      if (!canon_read_strhdr(p, end, &kn)) return false;
-      if (static_cast<size_t>(end - p) < kn) return false;
-      if (!utf8_valid(p, kn)) return false;
-      std::string_view k(reinterpret_cast<const char*>(p), kn);
-      for (auto& seen : keys)
-        if (seen == k) return false;
-      keys.push_back(k);
-      w.str(reinterpret_cast<const char*>(p), kn);
-      p += kn;
-      if (!canon_value(p, end, w, depth + 1)) return false;
-    }
-    return true;
-  }
-  return false;  // ext / reserved tags
+  return true;
 }
 
-// the canonical-writer byte-parity check: true iff this codec's
-// canonical re-encoding reproduces the exact input bytes (the
+// true iff raw is one canonical value and nothing follows it (the
 // precondition for columnarizing; mirrors columnar.py _canonical)
-static bool canonical_ok(const u8* raw, size_t len, Writer& scratch) {
-  scratch.buf.clear();
+static bool canonical_ok(const u8* raw, size_t len) {
   const u8* p = raw;
-  if (!canon_value(p, raw + len, scratch, 0)) return false;
-  if (p != raw + len) return false;
-  return scratch.buf.size() == len &&
-         std::memcmp(scratch.buf.data(), raw, len) == 0;
+  return canon_asis(p, raw + len, 0) && p == raw + len;
 }
 
 // ---------------------------------------------------------------------------
@@ -4300,7 +4277,6 @@ struct ColEncoder {
   std::unordered_map<u32, i128> run_clock;   // actor idx -> max seq
   i128 last_elem = 0;
   i128 last_key_elem = 0;
-  Writer canon_scratch;
   std::vector<Field> fields, op_fields;
 
   // per-level column cache: the field vocabulary is tiny and fixed,
@@ -4536,7 +4512,7 @@ struct ColEncoder {
   }
 
   void add(const u8* raw, size_t len) {
-    if (!canonical_ok(raw, len, canon_scratch)) {
+    if (!canonical_ok(raw, len)) {
       add_residual(raw, len);
       return;
     }
@@ -6863,6 +6839,32 @@ const uint8_t* amtpu_shard_buf(void* sp, int shard, int64_t* len) {
 }
 
 void amtpu_shard_free(void* sp) { delete static_cast<ShardSplit*>(sp); }
+
+// ---- request splice -------------------------------------------------------
+// The gateway keeps an apply_batch frame's change arrays as the frame's
+// bytes and splices them into the pool's payload
+// (automerge_tpu/utils/request_map.py).  That is exact only where a span
+// already is what msgpack-python's packb(unpackb(span)) gives, since the
+// pool copies op values verbatim into its patches.  `spans` holds n
+// (start, end) pairs of `data`, each one doc's changes array.  ops[i] is
+// the number of ops that doc's changes hold when its span is canonical
+// (canon_asis: valid utf-8, no float32, shortest headers, unique string
+// keys) and every change is a map whose 'ops', when present, is
+// an array; else -1, and the caller decodes that doc.  No pool, no
+// shared state: any thread may call it.
+
+void amtpu_scan_changes(const uint8_t* data, int64_t len,
+                        const int64_t* spans, int64_t n, int64_t* ops) {
+  for (int64_t i = 0; i < n; ++i) {
+    ops[i] = -1;
+    int64_t start = spans[2 * i], end = spans[2 * i + 1];
+    if (start < 0 || end < start || end > len) continue;
+    const uint8_t* p = data + start;
+    int64_t count = 0;
+    if (colnr::canon_asis(p, data + end, 0, &count) && p == data + end)
+      ops[i] = count;
+  }
+}
 
 }  // extern "C"
 

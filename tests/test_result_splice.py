@@ -28,11 +28,13 @@ ROOT_ID = '00000000-0000-0000-0000-000000000000'
 
 @pytest.fixture(autouse=True)
 def _hygiene():
+    # reset_all, not metrics_reset: a flush also observes the batch
+    # occupancy histogram, which a later file's test counts from zero
     faults.disarm()
-    telemetry.metrics_reset()
+    telemetry.reset_all()
     yield
     faults.disarm()
-    telemetry.metrics_reset()
+    telemetry.reset_all()
 
 
 def text_change(actor, seq, chars):
