@@ -7,9 +7,10 @@ child-first; undo/redo execute inverse ops captured in the undo stack.
 
 The module itself is the backend object (pass it as `backend=` to the
 frontend, mirroring the reference's `options.backend` injection seam,
-`/root/reference/frontend/index.js:98`).  The batched TPU engine in
-`automerge_tpu/parallel/engine.py` implements this same interface for
-thousands of documents per device pass.
+the reference's `frontend/index.js:98`).  It is also the scalar oracle:
+the batched pools (`automerge_tpu/native`) serve thousands of documents
+per device pass, and the tests hold their patches byte-identical to this
+module's.
 """
 
 from ..errors import AutomergeError, RangeError

@@ -9,7 +9,7 @@ pending requests over incoming patches with a small operational transform
 The frontend and backend each keep their own state and may be version-skewed:
 with an "immediate backend" (`options['backend']`) the round trip is
 synchronous; without one, requests queue and rebase -- that queued mode is
-exactly how the batched TPU engine drives thousands of frontends
+exactly how the batched native pool drives thousands of frontends
 asynchronously from one device pass.
 """
 

@@ -1,14 +1,16 @@
 """Native host runtime bindings.
 
 Loads `libamtpu_core.so` (built from /root/repo/native/) and exposes
-`NativeDocPool`: the C++ host runtime driving the same JAX device kernels
-as the Python `TPUDocPool`, with all per-op host stages (causal scheduling,
-columnar encoding, patch emission, mirror maintenance) in C++ and
-changes/patches crossing the boundary as msgpack bytes.
+`NativeDocPool`: the C++ host runtime driving the JAX device kernels
+(register resolution, RGA linearization, dominance indexes), with all
+per-op host stages (causal scheduling, columnar encoding, patch emission,
+mirror maintenance) in C++ and changes/patches crossing the boundary as
+msgpack bytes.
 
-`NativeDocPool.apply_batch(dict)` round-trips through msgpack for drop-in
-test parity with TPUDocPool; `apply_batch_bytes(bytes) -> bytes` is the
-zero-Python wire path the sidecar serves.
+`NativeDocPool.apply_batch(dict)` round-trips through msgpack so the
+tests can hold its patches to the scalar oracle (`automerge_tpu.backend`)
+dict for dict; `apply_batch_bytes(bytes) -> bytes` is the zero-Python
+wire path the sidecar serves.
 """
 
 import ctypes
@@ -1103,7 +1105,8 @@ def _raise_shard_errors(errors):
 
 
 class NativeDocPool:
-    """C++ host runtime + JAX kernels; drop-in for TPUDocPool."""
+    """C++ host runtime + JAX kernels: the batched resolver, patch-equal
+    to the scalar oracle."""
 
     #: window width of the register kernel (ops/registers.WINDOW)
     WINDOW = 8
@@ -2279,7 +2282,7 @@ class NativeDocPool:
             L.amtpu_dom_set_indexes(
                 bh, blk, idx.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
 
-    # -- dict-level API (test parity with TPUDocPool) -------------------
+    # -- dict-level API (the scalar oracle's change and patch dicts) ----
 
     _doc_key = staticmethod(doc_key)
 

@@ -152,8 +152,8 @@ def resolve_registers_members(time, actor, seq, mem_idx, is_del,
     `want_visible_before=False` drops the visible_before output AND its
     compute (a second [T, W+1, W+1] reduction chain) -- the native
     packed epilogue never reads it (C++ tracks its own running
-    visibility); only the engine path and the fused dominance derivation
-    need it.
+    visibility); only the mesh step (`parallel/mesh.py`) and the fused
+    dominance derivation need it.
     """
     T = time.shape[0]
     W = window

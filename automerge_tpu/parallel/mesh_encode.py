@@ -517,10 +517,9 @@ def verify_against_pool(workload, meta, out):
     same workload: per-doc clocks, and for every visibility-changing (or
     visible-set) list op its index and diff action, in op order.  Raises
     AssertionError on any mismatch."""
-    from .engine import TPUDocPool
+    from ..native import NativeDocPool
 
-    pool = TPUDocPool()
-    patches = pool.apply_batch(workload)
+    patches = NativeDocPool().apply_batch(workload)
     actors = meta['actors']
     alive = np.asarray(out['alive_after'])
     before = np.asarray(out['visible_before'])

@@ -6,7 +6,7 @@ replays it through a fresh backend on load
 `{"version": 1, "changes": [...]}` -- the change schema is already
 JSON-native, so the checkpoint format doubles as the wire format of the
 sidecar protocol.  Load replays through one batched `apply_changes` call
-(O(history), like the reference), and the TPU engine can replay the same
+(O(history), like the reference), and the native pool can replay the same
 columnar-encoded history in one device pass.
 """
 

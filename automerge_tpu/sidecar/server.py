@@ -126,9 +126,9 @@ class SidecarBackend:
     def apply_local_change(self, doc, request):
         """Local change request with the reference's validation and undo
         semantics (backend/index.js:175-197, 254-310).  The undo capture
-        runs inside the pool's runtime (amtpu_begin_local /
-        TPUDocPool.apply_local_change), reading the register mirror
-        in-process with the reference's topLevel gate."""
+        runs inside the pool's C++ runtime (amtpu_begin_local), reading
+        the register mirror in-process with the reference's topLevel
+        gate."""
         return self.pool.apply_local_change(doc, request)
 
     def get_patch(self, doc):

@@ -26,9 +26,8 @@ import msgpack
 from .. import telemetry
 from ..utils.common import env_str
 from .columnar import (corrupt_raises_value_error,  # noqa: F401
-                       decode_columnar, decode_columnar_dicts,
-                       decode_columnar_meta, encode_columnar,
-                       encode_columnar_dicts, storage_native_on)
+                       decode_columnar, decode_columnar_meta,
+                       encode_columnar, storage_native_on)
 
 FORMAT_V1 = 'amtpu-doc-v1'
 FORMAT_V2 = 'amtpu-doc-v2c'

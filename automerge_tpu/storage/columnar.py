@@ -486,13 +486,6 @@ def encode_columnar(raw_changes):
     return blob
 
 
-def encode_columnar_dicts(changes):
-    """Dict-level convenience (the Python engine pool): canonical
-    msgpack per change, then columnar."""
-    return encode_columnar(msgpack.packb(c, use_bin_type=True)
-                           for c in changes)
-
-
 class _Decoder(object):
     def __init__(self, blob):
         if blob[:4] != MAGIC:
@@ -671,9 +664,3 @@ def decode_columnar_meta(blob):
                 actor = seq = None
         out.append((raw, actor, seq))
     return out
-
-
-def decode_columnar_dicts(blob):
-    """Dict-level convenience: decoded change dicts in input order."""
-    return [msgpack.unpackb(raw, raw=False, strict_map_key=False)
-            for raw in decode_columnar(blob)]

@@ -3,8 +3,8 @@
 
 Holds many independent documents; applying changes notifies registered
 handlers (typically Connections).  Document-level independence is the
-framework's data-parallel axis: `automerge_tpu.parallel.engine` batches the
-op streams of every doc in a DocSet into one TPU resolve pass.
+framework's data-parallel axis: the native pool (`automerge_tpu.native`)
+batches the op streams of many docs into one TPU resolve pass.
 """
 
 from .. import backend as Backend

@@ -85,9 +85,8 @@ BATCH_LATENCY = registry.histogram(
     ('pool',))
 OPS = registry.counter(
     'amtpu_ops_total', 'Operations counted on committed batches only '
-    '(engine path: exact causally-applied ops; dict-level native path: '
-    'submitted ops incl. duplicates/queued -- the bytes path cannot '
-    'count without a decode it avoids)')
+    '(dict-level native path: submitted ops incl. duplicates/queued '
+    '-- the bytes path cannot count without a decode it avoids)')
 DOCS = registry.counter(
     'amtpu_docs_total', 'Documents touched by committed batches')
 SIDECAR_REQS = registry.counter(
@@ -640,7 +639,7 @@ def metrics_snapshot():
 
 def observe_batch(pool, seconds, docs=0, ops=0):
     """One apply-batch pass completed: latency histogram + counters.
-    `pool` names the entry point ('engine' | 'native' | 'sharded'), so
+    `pool` names the entry point ('native' | 'sharded' | 'mesh'), so
     whole-batch and per-shard latencies stay separate series."""
     BATCHES.labels(pool).inc()
     BATCH_LATENCY.labels(pool).observe(seconds)

@@ -9,10 +9,10 @@
 // passing columnar arrays by pointer, and changes/patches cross the
 // boundary as msgpack bytes.
 //
-// Semantics are a faithful port of automerge_tpu/parallel/engine.py, which
-// is itself byte-compatible with the reference backend
-// (/root/reference/backend/op_set.js).  Differential tests in
-// tests/test_native.py pin native output == Python pool output == oracle.
+// Semantics are byte-compatible with the reference backend's op_set.js
+// through the scalar oracle (automerge_tpu/backend).  Differential tests in
+// tests/test_engine_differential.py and tests/test_native.py pin native
+// output == oracle, on the full host path and on the kernel path.
 //
 // Build: see native/Makefile (g++ -O2 -shared -fPIC).
 

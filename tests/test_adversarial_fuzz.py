@@ -1,10 +1,10 @@
 """Adversarial differential fuzz (VERDICT r3 #8): schedules built to hit
 the engine's cliffs rather than its fast paths.
 
-Each scenario drives the scalar oracle, the batched Python pool, and the
-C++ native pool with IDENTICAL inputs and requires byte-identical
-patches at every delivery -- the same contract as
-tests/test_engine_differential.py, pointed at:
+Each scenario drives the scalar oracle and the C++ native pool, on the
+CPU default (`host`) and on the chip's kernel path (`kernel`), with
+IDENTICAL inputs and requires byte-identical patches at every delivery
+-- the same contract as tests/test_engine_differential.py, pointed at:
 
   * wide antichains: >8 concurrent writer streams per key (member-window
     overflow -> tiered kernel escalation, hostreg on the CPU backend; the
@@ -27,7 +27,8 @@ import pytest
 
 from automerge_tpu import backend as Backend
 from automerge_tpu.native import NativeDocPool
-from automerge_tpu.parallel.engine import TPUDocPool
+from tests.conftest import LanePool, native_lanes
+from tests.test_engine_differential import deliver_and_compare
 
 ROOT_ID = '00000000-0000-0000-0000-000000000000'
 
@@ -38,46 +39,11 @@ def seed_base(default):
 
 
 def deliver_all(change_batches, n_docs=1):
-    """Oracle + both pools, patch-equal at every step and at the end.
-
-    Runs the native pool in whatever mode the environment selects (the
-    full host path on the CPU test mesh); the `exec_mode` fixture
-    below re-runs every scenario with AMTPU_HOST_FULL=0 so the kernel
-    path faces the same adversarial schedules."""
-    oracle = {d: Backend.init() for d in range(n_docs)}
-    pools = [TPUDocPool(), NativeDocPool()]
-    for batch in change_batches:
-        want = {}
-        for doc, chs in batch.items():
-            oracle[doc], p = Backend.apply_changes(
-                oracle[doc], [dict(c) for c in chs])
-            want[doc] = p
-        for pool in pools:
-            got = pool.apply_batch(batch)
-            for doc in batch:
-                assert got[doc] == want[doc], (
-                    '%s patch mismatch doc %r' % (type(pool).__name__, doc))
-    for d in range(n_docs):
-        final = Backend.get_patch(oracle[d])
-        for pool in pools:
-            assert pool.get_patch(d) == final, type(pool).__name__
-    return oracle, pools
-
-
-@pytest.fixture(params=['default', 'kernel'])
-def exec_mode(request):
-    """Both execution modes face the adversarial schedules: the CPU
-    default (full host path) and the forced kernel path."""
-    if request.param == 'kernel':
-        prior = os.environ.get('AMTPU_HOST_FULL')
-        os.environ['AMTPU_HOST_FULL'] = '0'
-        yield 'kernel'
-        if prior is None:
-            os.environ.pop('AMTPU_HOST_FULL', None)
-        else:
-            os.environ['AMTPU_HOST_FULL'] = prior
-    else:
-        yield 'default'
+    """Oracle vs the native pool of the test's lane (the `pool_lane`
+    fixture sets the lane's env for the whole test), patch-equal at
+    every step and at the end.  Every scenario runs on the CPU default
+    (`host`) and on the chip's kernel path (`kernel`)."""
+    deliver_and_compare(change_batches, n_docs, pools=[NativeDocPool()])
 
 
 class TestWideAntichains:
@@ -87,7 +53,7 @@ class TestWideAntichains:
     byte-identical to the oracle in both execution modes."""
 
     @pytest.mark.parametrize('n_writers', [9, 12, 15, 17, 20, 33])
-    def test_map_hot_keys(self, n_writers, exec_mode):
+    def test_map_hot_keys(self, n_writers, pool_lane):
         rng = random.Random(seed_base(501) + n_writers)
         changes = []
         for seq in range(1, 4):
@@ -114,7 +80,7 @@ class TestWideAntichains:
             i += n
         deliver_all(batches)
 
-    def test_list_element_antichain(self, exec_mode):
+    def test_list_element_antichain(self, pool_lane):
         """14 writers concurrently assign the SAME list element (and one
         deletes it): a wide antichain on an element register, which must
         route through the overflow fallback WITH dominance work."""
@@ -142,21 +108,25 @@ class TestEscalationFallbackFree:
     """The ISSUE-2 acceptance lanes: the kernel path must be oracle-free
     on every width the ladder serves -- including the table-adversarial
     shape (same-change dup assigns) that produced the recorded 8,532
-    oracle-fallback rows, and a 100+ concurrent-live-writer antichain."""
+    oracle-fallback rows, and a 100+ concurrent-live-writer antichain.
+    The `host` lane resolves every register in C++ emit: it must be
+    oracle-free too, with every batch on the full host path."""
 
-    def _assert_kernel_fallback_free(self, run, exec_mode,
+    def _assert_kernel_fallback_free(self, run, pool_lane,
                                      expect_escalated=True):
         from automerge_tpu import telemetry
         telemetry.metrics_reset()
         run()
         snap = telemetry.metrics_snapshot()
         assert snap.get('fallback.oracle', 0) == 0, snap
-        if expect_escalated:
+        if pool_lane.lane == 'host':
+            assert snap.get('hostfull.batches', 0) > 0, snap
+        elif expect_escalated:
             assert any(k.startswith('fallback.escalated.w') and v > 0
-                       for k, v in snap.items()), (exec_mode, snap)
+                       for k, v in snap.items()), (pool_lane.lane, snap)
 
     @pytest.mark.parametrize('n_writers', [9, 15, 17, 33, 100, 120])
-    def test_concurrent_live_writers_one_key(self, n_writers, exec_mode):
+    def test_concurrent_live_writers_one_key(self, n_writers, pool_lane):
         """n fully concurrent live writers on one key in ONE batch: the
         widest antichain shape, resolved without a single oracle row."""
         writers = [{'actor': 'w%03d' % a, 'seq': 1, 'deps': {},
@@ -164,9 +134,9 @@ class TestEscalationFallbackFree:
                              'value': 'w%03d' % a}]}
                    for a in range(n_writers)]
         self._assert_kernel_fallback_free(
-            lambda: deliver_all([{0: writers}]), exec_mode)
+            lambda: deliver_all([{0: writers}]), pool_lane)
 
-    def test_table_shape_dup_assigns(self, exec_mode):
+    def test_table_shape_dup_assigns(self, pool_lane):
         """Config-4-shaped rows: concurrent field updates where changes
         assign the SAME row key twice (the member-window-unholdable
         shape) -- previously all-oracle, now all-ladder."""
@@ -192,12 +162,14 @@ class TestEscalationFallbackFree:
             updates.append({'actor': 'a%d' % a, 'seq': 1,
                             'deps': {'setup': 1}, 'ops': ops})
         self._assert_kernel_fallback_free(
-            lambda: deliver_all([{0: [setup]}, {0: updates}]), exec_mode)
+            lambda: deliver_all([{0: [setup]}, {0: updates}]), pool_lane)
 
-    def test_oracle_referee_parity(self, exec_mode):
+    def test_oracle_referee_parity(self, pool_lane):
         """AMTPU_ESCALATE=0 pins the referee: the host oracle must
-        produce byte-identical patches to the ladder (and the run must
-        actually take the oracle path -- fallback.oracle > 0)."""
+        produce byte-identical patches to the ladder (and the kernel
+        lane must actually take the oracle path -- fallback.oracle > 0;
+        the `host` lane has no ladder to switch off and stays on its
+        full host path, oracle-free)."""
         from automerge_tpu import telemetry
         prior = os.environ.get('AMTPU_ESCALATE')
         os.environ['AMTPU_ESCALATE'] = '0'
@@ -209,14 +181,18 @@ class TestEscalationFallbackFree:
                        for a in range(20)]
             deliver_all([{0: writers}])
             snap = telemetry.metrics_snapshot()
-            assert snap.get('fallback.oracle', 0) > 0, snap
+            if pool_lane.lane == 'host':
+                assert snap.get('fallback.oracle', 0) == 0, snap
+                assert snap.get('hostfull.batches', 0) > 0, snap
+            else:
+                assert snap.get('fallback.oracle', 0) > 0, snap
         finally:
             if prior is None:
                 os.environ.pop('AMTPU_ESCALATE', None)
             else:
                 os.environ['AMTPU_ESCALATE'] = prior
 
-    def test_wide_antichain_with_list_dominance(self, exec_mode):
+    def test_wide_antichain_with_list_dominance(self, pool_lane):
         """30 concurrent writers on ONE list element register: escalation
         must compose with the dominance stage, not just map emits."""
         base = {'actor': 'base', 'seq': 1, 'deps': {}, 'ops': [
@@ -234,7 +210,7 @@ class TestEscalationFallbackFree:
             writers.append({'actor': 'w%02d' % a, 'seq': 1,
                             'deps': {'base': 1}, 'ops': [op]})
         self._assert_kernel_fallback_free(
-            lambda: deliver_all([{0: [base]}, {0: writers}]), exec_mode)
+            lambda: deliver_all([{0: [base]}, {0: writers}]), pool_lane)
 
 
 @pytest.fixture(params=['packed', 'unpacked'])
@@ -317,7 +293,7 @@ class TestPackedEpilogueParity:
         return batches
 
     def test_member_epilogue_byte_parity(self, packed_epilogue,
-                                         exec_mode):
+                                         pool_lane):
         from automerge_tpu import telemetry
         telemetry.metrics_reset()
         rng = random.Random(seed_base(70707))
@@ -334,7 +310,7 @@ class TestPackedEpilogueParity:
                 os.environ['AMTPU_HOST_REG'] = prior
         snap = telemetry.metrics_snapshot()
         assert snap.get('fallback.oracle', 0) == 0, snap
-        if exec_mode == 'kernel':
+        if pool_lane.lane == 'kernel':
             # the toggle must actually select the epilogue under test
             if packed_epilogue == 'packed':
                 assert snap.get('collect.packed_member_batches', 0) > 0, \
@@ -349,7 +325,7 @@ class TestPackedEpilogueParity:
 
     @pytest.mark.parametrize('seed', [1, 2, 3])
     def test_rotating_hot_key_fuzz(self, seed, packed_epilogue,
-                                   exec_mode):
+                                   pool_lane):
         """Randomized widths/depths: writer counts rotate through the
         base window, the first tier, and multi-tier territory."""
         rng = random.Random(seed_base(81000 + seed))
@@ -361,7 +337,7 @@ class TestPackedEpilogueParity:
 
 
 class TestReversedCausalChains:
-    def test_deep_chain_reversed(self, exec_mode):
+    def test_deep_chain_reversed(self, pool_lane):
         """120-deep cross-actor dependency chain delivered fully
         reversed: every change but the first buffers, then one fixpoint
         admits the whole chain."""
@@ -390,7 +366,7 @@ class TestReversedCausalChains:
             i += n
         deliver_all(batches)
 
-    def test_cross_doc_reversed_streams(self, exec_mode):
+    def test_cross_doc_reversed_streams(self, pool_lane):
         """Several docs' chains interleaved, each doc's stream reversed
         independently within one multi-doc batch sequence."""
         rng = random.Random(seed_base(602))
@@ -426,7 +402,7 @@ class TestUndoRedoUnderMerge:
         all three backends byte-identical at every step."""
         rng = random.Random(seed_base(701))
         oracle = Backend.init()
-        pools = [TPUDocPool(), NativeDocPool()]
+        pools = native_lanes()
         local_seq = 0
         remote_seqs = {}
         can_undo = 0
@@ -461,17 +437,17 @@ class TestUndoRedoUnderMerge:
                 oracle, want = Backend.apply_changes(oracle, [dict(ch)])
                 for pool in pools:
                     got = pool.apply_batch({0: [dict(ch)]})[0]
-                    assert got == want, (step, type(pool).__name__)
+                    assert got == want, (step, pool.lane)
                 continue
             oracle, want = Backend.apply_local_change(oracle, dict(req))
             for pool in pools:
                 got = pool.apply_local_change(0, dict(req))
                 assert got == want, (step, req['requestType'],
-                                     type(pool).__name__)
+                                     pool.lane)
 
         final = Backend.get_patch(oracle)
         for pool in pools:
-            assert pool.get_patch(0) == final, type(pool).__name__
+            assert pool.get_patch(0) == final, pool.lane
 
 
 class TestSaveLoadMidStream:
@@ -494,7 +470,7 @@ class TestSaveLoadMidStream:
         changes = WorkloadGen(seed, n_actors=4,
                               structure='mixed').generate(40)
         half = len(changes) // 2
-        pools = [TPUDocPool(), NativeDocPool()]
+        pools = native_lanes()
         for pool in pools:
             pool.apply_batch({0: [dict(c) for c in changes[:half]]})
 
@@ -503,7 +479,7 @@ class TestSaveLoadMidStream:
         for pool in pools:
             blob = pool.save(0)
             blobs.append(blob)
-            fresh = type(pool)()
+            fresh = LanePool(pool.lane)
             fresh.load(0, blob)
             restored.append(fresh)
         # both backends checkpoint the same applied history
@@ -519,7 +495,7 @@ class TestSaveLoadMidStream:
                      for r in storage.checkpoint_raw_changes(blobs[0])])
         for pool in restored:
             assert pool.get_patch(0) == Backend.get_patch(oracle), \
-                type(pool).__name__
+                pool.lane
 
         # continuation via the redelivery protocol: EVERYTHING shuffled
         # (first half again + second half); applied changes dedup as
@@ -530,10 +506,10 @@ class TestSaveLoadMidStream:
             oracle, want = Backend.apply_changes(oracle, [dict(ch)])
             for pool in restored:
                 got = pool.apply_batch({0: [dict(ch)]})[0]
-                assert got == want, type(pool).__name__
+                assert got == want, pool.lane
         final = Backend.get_patch(oracle)
         for pool in restored:
-            assert pool.get_patch(0) == final, type(pool).__name__
+            assert pool.get_patch(0) == final, pool.lane
         # nothing left buffered anywhere
         for pool in restored:
             assert pool.get_missing_deps(0) == {}
@@ -554,7 +530,7 @@ class TestTableAdversarial:
         linked (null -> real path transitions that are never cached).
     """
 
-    def test_concurrent_row_lifecycle(self, exec_mode):
+    def test_concurrent_row_lifecycle(self, pool_lane):
         # every per-actor row object is created up front; afterwards six
         # actors concurrently update/unlink/relink the same rows with NO
         # causal ordering (deps={}), delivered fully shuffled -- maximal
@@ -593,7 +569,7 @@ class TestTableAdversarial:
         rng.shuffle(changes)
         deliver_all([{0: [setup]}] + [{0: [ch]} for ch in changes])
 
-    def test_two_parent_row_first_link_removed(self, exec_mode):
+    def test_two_parent_row_first_link_removed(self, pool_lane):
         # row under two tables; removing the FIRST link (inbound[0])
         # must flip emitted paths to the second parent
         batches = [
@@ -622,7 +598,7 @@ class TestTableAdversarial:
         ]
         deliver_all(batches)
 
-    def test_nested_map_written_around_link(self, exec_mode):
+    def test_nested_map_written_around_link(self, pool_lane):
         rng = random.Random(seed_base(60602))
         ops = [{'action': 'makeTable', 'obj': 'tb'},
                {'action': 'link', 'obj': ROOT_ID, 'key': 'rows',

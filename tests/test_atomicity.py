@@ -12,11 +12,13 @@ import pytest
 
 from automerge_tpu.errors import AutomergeError
 from automerge_tpu.native import NativeDocPool, ShardedNativePool
-from automerge_tpu.parallel.engine import TPUDocPool
 
 ROOT = '00000000-0000-0000-0000-000000000000'
 
-POOLS = [NativeDocPool, TPUDocPool, lambda: ShardedNativePool(n_shards=2)]
+#: every pool lane (tests/conftest.py): the CPU default, the chip's
+#: kernel path, and a two-shard pool
+LANES = pytest.mark.parametrize('pool_lane', ['host', 'kernel', 'sharded'],
+                                indirect=True)
 
 
 def good(seq, key='k', value=1):
@@ -25,9 +27,9 @@ def good(seq, key='k', value=1):
                      'value': value}]}
 
 
-@pytest.mark.parametrize('make_pool', POOLS)
-def test_failed_batch_fully_rolls_back(make_pool):
-    pool = make_pool()
+@LANES
+def test_failed_batch_fully_rolls_back(pool_lane):
+    pool = pool_lane()
     bad = {'actor': 'A', 'seq': 2, 'deps': {},
            'ops': [{'action': 'set', 'obj': 'nonexistent', 'key': 'x',
                     'value': 1}]}
@@ -42,9 +44,9 @@ def test_failed_batch_fully_rolls_back(make_pool):
     assert pool.get_patch('d')['clock'] == {'A': 1}
 
 
-@pytest.mark.parametrize('make_pool', POOLS)
-def test_failed_batch_restores_causal_queue(make_pool):
-    pool = make_pool()
+@LANES
+def test_failed_batch_restores_causal_queue(pool_lane):
+    pool = pool_lane()
     # queue a change with an unmet dependency, then fail a later batch
     future = good(2, key='later')
     pool.apply_changes('d', [future])
@@ -61,9 +63,9 @@ def test_failed_batch_restores_causal_queue(make_pool):
     assert {d['key'] for d in patch['diffs']} == {'k', 'later'}
 
 
-@pytest.mark.parametrize('make_pool', POOLS)
-def test_missing_list_element_fails_before_commit(make_pool):
-    pool = make_pool()
+@LANES
+def test_missing_list_element_fails_before_commit(pool_lane):
+    pool = pool_lane()
     pool.apply_changes('d', [
         {'actor': 'A', 'seq': 1, 'deps': {},
          'ops': [{'action': 'makeText', 'obj': 'T'},
@@ -83,9 +85,9 @@ def test_missing_list_element_fails_before_commit(make_pool):
     assert pool.get_patch('d')['clock'] == {'A': 2}
 
 
-@pytest.mark.parametrize('make_pool', POOLS)
-def test_inconsistent_seq_reuse_rejected_without_commit(make_pool):
-    pool = make_pool()
+@LANES
+def test_inconsistent_seq_reuse_rejected_without_commit(pool_lane):
+    pool = pool_lane()
     pool.apply_changes('d', [good(1)])
     with pytest.raises(AutomergeError, match='Inconsistent reuse'):
         pool.apply_changes('d', [good(1, value=999)])
@@ -93,12 +95,12 @@ def test_inconsistent_seq_reuse_rejected_without_commit(make_pool):
     assert pool.apply_changes('d', [good(1)])['diffs'] == []
 
 
-@pytest.mark.parametrize('make_pool', POOLS)
-def test_multi_error_batches_surface_first_error_in_op_order(make_pool):
+@LANES
+def test_multi_error_batches_surface_first_error_in_op_order(pool_lane):
     """When a batch contains several invalid ops, the FIRST one in
     application order wins -- the oracle applies ops strictly in order, so
     every backend must report the same error for the same input."""
-    pool = make_pool()
+    pool = pool_lane()
     pool.apply_changes('d', [
         {'actor': 'A', 'seq': 1, 'deps': {},
          'ops': [{'action': 'makeText', 'obj': 'T'},
@@ -113,12 +115,12 @@ def test_multi_error_batches_surface_first_error_in_op_order(make_pool):
     assert pool.get_patch('d')['clock'] == {'A': 1}
 
 
-@pytest.mark.parametrize('make_pool', POOLS)
-def test_assign_before_insert_in_same_change_rejected(make_pool):
+@LANES
+def test_assign_before_insert_in_same_change_rejected(pool_lane):
     """An assign referencing an element inserted LATER in the same change
     must error: the oracle applies ops in order, so the element does not
     exist yet when the assign runs."""
-    pool = make_pool()
+    pool = pool_lane()
     bad = {'actor': 'A', 'seq': 1, 'deps': {},
            'ops': [{'action': 'makeText', 'obj': 'T'},
                    {'action': 'set', 'obj': 'T', 'key': 'A:1',

@@ -44,27 +44,6 @@ def _fault_hygiene():
     telemetry.metrics_reset()
 
 
-@pytest.fixture(params=['default', 'kernel'])
-def exec_mode(request):
-    """Both execution modes face every fault lane: the CPU default
-    (full host path; device sites are unreachable by construction) and
-    the forced kernel path (AMTPU_HOST_REG=0 keeps the hot-key batch on
-    the escalation ladder instead of the CPU hostreg shortcut)."""
-    if request.param == 'kernel':
-        prior = {k: os.environ.get(k)
-                 for k in ('AMTPU_HOST_FULL', 'AMTPU_HOST_REG')}
-        os.environ['AMTPU_HOST_FULL'] = '0'
-        os.environ['AMTPU_HOST_REG'] = '0'
-        yield 'kernel'
-        for k, v in prior.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-    else:
-        yield 'default'
-
-
 def build_docs():
     """Six plain map docs plus one 20-concurrent-writer hot doc, so the
     kernel path exercises dispatch, collect, AND the escalation ladder
@@ -104,15 +83,15 @@ class TestFaultMatrix:
 
     # (site, fires_in): device-path sites cannot fire on the full host
     # path -- those lanes assert the armed-but-unreachable contract
-    SITES = [('native.begin', ('default', 'kernel')),
-             ('native.mid', ('default', 'kernel')),
+    SITES = [('native.begin', ('host', 'kernel')),
+             ('native.mid', ('host', 'kernel')),
              ('device.dispatch', ('kernel',)),
              ('device.collect', ('kernel',)),
              ('escalation.tier', ('kernel',))]
 
     @pytest.mark.parametrize('site,fires_in',
                              SITES, ids=[s for s, _ in SITES])
-    def test_transient_retries_to_parity(self, site, fires_in, exec_mode):
+    def test_transient_retries_to_parity(self, site, fires_in, pool_lane):
         """Two forced transient faults: the batch must complete with
         results byte-identical to the fault-free run and
         resilience.retry.success >= 1 (the ISSUE-4 acceptance lane)."""
@@ -122,7 +101,7 @@ class TestFaultMatrix:
         got = NativeDocPool().apply_batch(build_docs())
         snap = telemetry.metrics_snapshot()
         assert_byte_parity(got, want)
-        if exec_mode in fires_in:
+        if pool_lane.lane in fires_in:
             assert snap.get('resilience.fault_injected', 0) == 2, snap
             assert snap.get('resilience.retry.success', 0) >= 1, snap
             assert snap.get('resilience.rollback', 0) >= 2, snap
@@ -134,7 +113,7 @@ class TestFaultMatrix:
     @pytest.mark.parametrize('site,fires_in',
                              SITES, ids=[s for s, _ in SITES])
     def test_permanent_quarantines_poison_doc(self, site, fires_in,
-                                              exec_mode):
+                                              pool_lane):
         """A permanent fault pinned to one doc: that doc alone is
         quarantined (per-doc error envelope) and every other doc's
         patch is byte-identical to the fault-free run."""
@@ -151,7 +130,7 @@ class TestFaultMatrix:
         pool = NativeDocPool()
         got = pool.apply_batch(build_docs())
         snap = telemetry.metrics_snapshot()
-        if exec_mode not in fires_in:
+        if pool_lane.lane not in fires_in:
             assert_byte_parity(got, want)
             assert snap.get('resilience.fault_injected', 0) == 0, snap
             return
@@ -168,7 +147,7 @@ class TestFaultMatrix:
         assert msgpack.packb(healed, use_bin_type=True) == \
             msgpack.packb(want[poison], use_bin_type=True)
 
-    def test_transient_budget_exhaustion_quarantines(self, exec_mode):
+    def test_transient_budget_exhaustion_quarantines(self, pool_lane):
         """An unbounded transient fault pinned to one doc exhausts the
         retry budget and degrades into quarantine -- bounded retries,
         not an infinite stall."""
@@ -182,7 +161,7 @@ class TestFaultMatrix:
         assert snap.get('resilience.retry.exhausted', 0) >= 1, snap
         assert snap.get('resilience.quarantined') == 1, snap
 
-    def test_degraded_path_heals_device_poison(self, exec_mode):
+    def test_degraded_path_heals_device_poison(self, pool_lane):
         """AMTPU_DEGRADE=1: a doc whose device path is permanently
         poisoned commits via the full-host path instead of quarantine;
         counted as resilience.degraded, NOT fallback.oracle."""
@@ -196,7 +175,7 @@ class TestFaultMatrix:
             os.environ.pop('AMTPU_DEGRADE', None)
         snap = telemetry.metrics_snapshot()
         assert_byte_parity(got, want)
-        if exec_mode == 'kernel':
+        if pool_lane.lane == 'kernel':
             assert snap.get('resilience.degraded') == 1, snap
             assert not snap.get('resilience.quarantined'), snap
         assert not snap.get('fallback.oracle'), snap
@@ -230,7 +209,7 @@ class TestFaultMatrix:
                   'resilience.degraded', 'fallback.oracle'):
             assert not snap.get(k), (k, snap)
 
-    def test_checkpoint_load_fault_surfaces_and_clears(self, exec_mode):
+    def test_checkpoint_load_fault_surfaces_and_clears(self, pool_lane):
         """checkpoint.load faults surface to the caller (the WAL replay
         driver owns the retry policy there); a retry after the fault
         clears restores byte-identical state."""
@@ -246,7 +225,7 @@ class TestFaultMatrix:
         for d in want:
             assert dst.get_patch(d) == src.get_patch(d)
 
-    def test_env_armed_spec(self, exec_mode):
+    def test_env_armed_spec(self, pool_lane):
         """AMTPU_FAULT env syntax arms exactly like the programmatic
         API (the sidecar server subprocess path)."""
         want = reference_patches()
@@ -269,7 +248,7 @@ class TestFaultMatrix:
 
 class TestShardedIsolation:
     @pytest.mark.parametrize('mode', ['pipeline', 'threads'])
-    def test_poison_doc_stays_inside_its_shard(self, mode, exec_mode):
+    def test_poison_doc_stays_inside_its_shard(self, mode, pool_lane):
         want = reference_patches()
         telemetry.metrics_reset()
         faults.arm('native.mid', 'permanent', 1.0, match=POISON)
@@ -282,7 +261,7 @@ class TestShardedIsolation:
 
     @pytest.mark.parametrize('mode', ['pipeline', 'threads'])
     def test_transient_shard_failure_retries_to_parity(self, mode,
-                                                       exec_mode):
+                                                       pool_lane):
         want = reference_patches()
         telemetry.metrics_reset()
         faults.arm('native.begin', 'transient', 1.0, count=1)
@@ -293,7 +272,7 @@ class TestShardedIsolation:
         assert snap.get('resilience.retry.success', 0) >= 1, snap
 
     def test_validation_error_preempts_isolation_atomically(self,
-                                                            exec_mode):
+                                                            pool_lane):
         """A begin-level validation error fires before any injected
         fault, so isolation never starts: the whole batch raises AND
         (via rollback) commits nothing -- after dropping the bad doc,
@@ -317,7 +296,7 @@ class TestShardedIsolation:
         assert telemetry.metrics_snapshot().get(
             'resilience.quarantined') == 1
 
-    def test_protocol_errors_still_raise(self, exec_mode):
+    def test_protocol_errors_still_raise(self, pool_lane):
         """Validation errors are NOT infrastructure faults: the
         whole-batch raise contract survives the resilience layer."""
         from automerge_tpu.errors import AutomergeError

@@ -9,7 +9,6 @@ the `restore_from_store` parallel cold start: summary accounting,
 `storage.restore.*` counters, and the corrupt-blob quarantine."""
 
 import os
-import random
 
 import pytest
 
@@ -25,27 +24,6 @@ def _reset():
     telemetry.reset_all()
     yield
     telemetry.reset_all()
-
-
-@pytest.fixture(params=['default', 'kernel'])
-def exec_mode(request):
-    """Both execution modes face the parity lanes (same pattern as
-    tests/test_storage_native.py): folded clock reads resolve host-side
-    in C++, so their output must match under the CPU default AND the
-    forced kernel path."""
-    if request.param == 'kernel':
-        prior = {k: os.environ.get(k)
-                 for k in ('AMTPU_HOST_FULL', 'AMTPU_HOST_REG')}
-        os.environ['AMTPU_HOST_FULL'] = '0'
-        os.environ['AMTPU_HOST_REG'] = '0'
-        yield 'kernel'
-        for k, v in prior.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-    else:
-        yield 'default'
 
 
 @pytest.fixture
@@ -97,7 +75,7 @@ def _build_twins(fold_env, make_folded, make_unfolded, n_docs=12,
     return pools
 
 
-def test_fold_frees_pairs_and_acct_reconciles(fold_env, exec_mode):
+def test_fold_frees_pairs_and_acct_reconciles(fold_env, pool_lane):
     folded, unfolded = _build_twins(fold_env, NativeDocPool,
                                     NativeDocPool)
     _ids, fstats = folded.doc_stats()
@@ -117,7 +95,7 @@ def test_fold_frees_pairs_and_acct_reconciles(fold_env, exec_mode):
     assert int(ustats[:, 7].sum()) == 0
 
 
-def test_causal_queries_parity(fold_env, exec_mode):
+def test_causal_queries_parity(fold_env, pool_lane):
     folded, unfolded = _build_twins(fold_env, NativeDocPool,
                                     NativeDocPool)
     for d in range(12):
@@ -138,7 +116,7 @@ def test_causal_queries_parity(fold_env, exec_mode):
                     == unfolded.get_changes_for_actor(doc, actor, after)
 
 
-def test_fold_then_more_history_parity(fold_env, exec_mode):
+def test_fold_then_more_history_parity(fold_env, pool_lane):
     """Changes applied AFTER a fold must seed their deps through the
     folded rows (update_states reads all_deps via the fold table) --
     the drift the ISSUE forbids."""

@@ -1,7 +1,8 @@
-"""Differential tests: the batched TPU engine must emit patches
-byte-identical to the scalar oracle for the same change streams -- the
-project's generalization of the reference's hand-built change/patch JSON
-contract (`/root/reference/test/backend_test.js`).
+"""Differential tests: the native pool must emit patches byte-identical
+to the scalar oracle for the same change streams, on the CPU default
+(full C++ host path) and on the chip's dispatch (C++ host plus the JAX
+kernels) -- the project's generalization of the reference's hand-built
+change/patch JSON contract (its `test/backend_test.js`).
 """
 
 import random
@@ -10,36 +11,39 @@ import pytest
 
 import automerge_tpu as am
 from automerge_tpu import backend as Backend
-from automerge_tpu.parallel.engine import TPUDocPool
+from tests.conftest import native_lanes
 
 ROOT_ID = '00000000-0000-0000-0000-000000000000'
 
 
-def deliver_and_compare(change_batches, n_docs=1):
-    """Feeds identical change batches to the oracle and the pool; asserts
-    patch equality at every step and final getPatch equality."""
+def deliver_and_compare(change_batches, n_docs=1, pools=None):
+    """Oracle vs native `host` vs native `kernel` (or vs `pools`) on
+    identical change batches: patch-equal at every delivery and
+    getPatch-equal at the end."""
     oracle_states = {d: Backend.init() for d in range(n_docs)}
-    pool = TPUDocPool()
+    pools = native_lanes() if pools is None else pools
 
     for batch in change_batches:
         # batch: {doc: [changes]}
         expected = {}
         for doc, changes in batch.items():
             oracle_states[doc], patch = Backend.apply_changes(
-                oracle_states[doc], changes)
+                oracle_states[doc], [dict(c) for c in changes])
             expected[doc] = patch
-        got = pool.apply_batch(batch)
-        for doc in batch:
-            assert got[doc] == expected[doc], (
-                'patch mismatch for doc %r:\nexpected %r\ngot      %r'
-                % (doc, expected[doc], got[doc]))
+        for pool in pools:
+            got = pool.apply_batch(batch)
+            for doc in batch:
+                assert got[doc] == expected[doc], (
+                    '%r patch mismatch for doc %r:\nexpected %r\ngot'
+                    '      %r' % (pool, doc, expected[doc], got[doc]))
 
     for doc in range(n_docs):
-        expect_patch = Backend.get_patch(oracle_states[doc])
-        got_patch = pool.get_patch(doc)
-        assert got_patch == expect_patch, (
-            'getPatch mismatch:\nexpected %r\ngot      %r'
-            % (expect_patch, got_patch))
+        want = Backend.get_patch(oracle_states[doc])
+        for pool in pools:
+            got = pool.get_patch(doc)
+            assert got == want, (
+                '%r getPatch mismatch:\nexpected %r\ngot      %r'
+                % (pool, want, got))
 
 
 class TestMapParity:
@@ -269,32 +273,6 @@ class TestRandomWorkloads:
             [{d: all_changes[d] for d in range(4)}], n_docs=4)
 
 
-def deliver_and_compare_all(change_batches, n_docs=1):
-    """Three-way differential: oracle vs TPUDocPool vs NativeDocPool,
-    patch-equal at every delivery and getPatch-equal at the end."""
-    from automerge_tpu.native import NativeDocPool
-
-    oracle_states = {d: Backend.init() for d in range(n_docs)}
-    pools = [TPUDocPool(), NativeDocPool()]
-
-    for batch in change_batches:
-        expected = {}
-        for doc, changes in batch.items():
-            oracle_states[doc], patch = Backend.apply_changes(
-                oracle_states[doc], [dict(c) for c in changes])
-            expected[doc] = patch
-        for pool in pools:
-            got = pool.apply_batch(batch)
-            for doc in batch:
-                assert got[doc] == expected[doc], (
-                    '%s patch mismatch for doc %r'
-                    % (type(pool).__name__, doc))
-    for doc in range(n_docs):
-        want = Backend.get_patch(oracle_states[doc])
-        for pool in pools:
-            assert pool.get_patch(doc) == want, type(pool).__name__
-
-
 class TestRotatingFuzz:
     """Seed-rotating nightly-style fuzz (VERDICT round-1 item 9): larger
     workloads than the fixed-seed suites, driving the NATIVE pool too.
@@ -328,12 +306,12 @@ class TestRotatingFuzz:
                 rng.shuffle(chunk)
             batches.append({0: chunk})
             i += k
-        deliver_and_compare_all(batches)
+        deliver_and_compare(batches)
 
     def test_rotating_multi_doc_fuzz(self):
         seed = self.base_seed()
         print('fuzz seed: %d (override with AMTPU_FUZZ_SEED)' % seed)
         streams = [WorkloadGen(seed + 100 + d, structure='mixed')
                    .generate(25) for d in range(6)]
-        deliver_and_compare_all(
+        deliver_and_compare(
             [{d: streams[d] for d in range(6)}], n_docs=6)

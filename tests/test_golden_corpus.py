@@ -1,7 +1,8 @@
 """Replays the golden corpus mechanically derived from the reference's own
 backend test fixtures (`/root/reference/test/backend_test.js`, extracted
 by tools/extract_golden_corpus.py) against every backend: the scalar
-oracle, both pools, and the sidecar protocol surface.
+oracle, the native pool on the CPU default and on the chip's kernel
+path, and the sidecar protocol surface.
 
 The expected patches are the reference suite's own assertions -- this is
 the differential-testing seam SURVEY.md section 4 calls for: hand-built
@@ -16,8 +17,8 @@ import pytest
 
 from automerge_tpu import backend as Backend
 from automerge_tpu.native import NativeDocPool
-from automerge_tpu.parallel.engine import TPUDocPool
 from automerge_tpu.sidecar.server import SidecarBackend
+from tests.conftest import lane_env
 
 CORPUS = os.path.join(os.path.dirname(__file__), 'golden',
                       'backend_corpus.json')
@@ -99,8 +100,9 @@ def test_native_pool_matches_reference_fixtures(case):
 
 
 @pytest.mark.parametrize('case', CASES, ids=case_ids())
-def test_tpu_pool_matches_reference_fixtures(case):
-    run_against_pool(case, TPUDocPool())
+def test_kernel_path_matches_reference_fixtures(case):
+    with lane_env('kernel'):
+        run_against_pool(case, NativeDocPool())
 
 
 @pytest.mark.parametrize('case', CASES, ids=case_ids())

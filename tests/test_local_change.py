@@ -1,5 +1,6 @@
-"""Differential tests for apply_local_change / undo / redo across all three
-backends (scalar oracle, TPUDocPool, NativeDocPool) plus the sidecar.
+"""Differential tests for apply_local_change / undo / redo across the
+scalar oracle and the native pool on the CPU default and on the chip's
+kernel path, plus the sidecar.
 
 The reference semantics under test (`/root/reference/backend/index.js:175-310`,
 `backend/op_set.js:193-200, 233-250, 296-308`):
@@ -18,8 +19,8 @@ from automerge_tpu.backend import (apply_local_change, get_missing_changes,
                                    get_patch, init)
 from automerge_tpu.errors import RangeError
 from automerge_tpu.native import NativeDocPool, ShardedNativePool
-from automerge_tpu.parallel.engine import TPUDocPool
 from automerge_tpu.sidecar.server import SidecarBackend
+from tests.conftest import native_lanes
 
 ROOT = '00000000-0000-0000-0000-000000000000'
 
@@ -39,16 +40,13 @@ def drive_pool(pool, reqs, doc='d'):
 
 def assert_three_way(reqs):
     state, oracle = drive_oracle(reqs)
-    npool, tpool = NativeDocPool(), TPUDocPool()
-    nat = drive_pool(npool, reqs)
-    tpu = drive_pool(tpool, reqs)
-    for i, (o, n, t) in enumerate(zip(oracle, nat, tpu)):
-        assert o == n, 'native patch mismatch at request %d' % i
-        assert o == t, 'tpu-pool patch mismatch at request %d' % i
-    assert get_patch(state) == npool.get_patch('d') == tpool.get_patch('d')
     hist = get_missing_changes(state, {})
-    assert hist == npool.get_missing_changes('d', {})
-    assert hist == tpool.get_missing_changes('d', {})
+    for pool in native_lanes():
+        got = drive_pool(pool, reqs)
+        for i, (o, g) in enumerate(zip(oracle, got)):
+            assert o == g, '%r patch mismatch at request %d' % (pool, i)
+        assert get_patch(state) == pool.get_patch('d'), pool
+        assert hist == pool.get_missing_changes('d', {}), pool
 
 
 def test_undo_skips_same_change_object_creation():
@@ -183,10 +181,10 @@ def test_local_then_remote_patch_flags():
     assert pool.get_patch('d')['canUndo'] is True
 
 
-@pytest.mark.parametrize('make_pool', [
-    NativeDocPool, TPUDocPool, lambda: ShardedNativePool(n_shards=2)])
-def test_error_parity(make_pool):
-    pool = make_pool()
+@pytest.mark.parametrize('pool_lane', ['host', 'kernel', 'sharded'],
+                         indirect=True)
+def test_error_parity(pool_lane):
+    pool = pool_lane()
     with pytest.raises(TypeError):
         pool.apply_local_change('d', {'requestType': 'change', 'seq': 1,
                                       'deps': {}, 'ops': []})

@@ -1,17 +1,16 @@
 """Differential tests for the C++ native host runtime (NativeDocPool):
-its patches must equal the Python pool's and the scalar oracle's for the
-same change streams, including msgpack round-trips of every value type.
+its patches must equal the scalar oracle's for the same change streams,
+on the CPU default and on the chip's kernel path, including msgpack
+round-trips of every value type.
 """
 
 import random
 
 import pytest
 
-import automerge_tpu as am
 from automerge_tpu import backend as Backend
-from automerge_tpu.parallel.engine import TPUDocPool
 
-from test_engine_differential import WorkloadGen
+from test_engine_differential import WorkloadGen, deliver_and_compare
 
 ROOT_ID = '00000000-0000-0000-0000-000000000000'
 
@@ -19,32 +18,6 @@ ROOT_ID = '00000000-0000-0000-0000-000000000000'
 def native_pool():
     from automerge_tpu.native import NativeDocPool
     return NativeDocPool()
-
-
-def deliver_and_compare(change_batches, n_docs=1):
-    """Feeds identical batches to oracle, Python pool and native pool;
-    asserts patch equality at every step and getPatch equality at the end."""
-    oracle_states = {d: Backend.init() for d in range(n_docs)}
-    py = TPUDocPool()
-    nat = native_pool()
-
-    for batch in change_batches:
-        expected = {}
-        for doc, changes in batch.items():
-            oracle_states[doc], patch = Backend.apply_changes(
-                oracle_states[doc], changes)
-            expected[doc] = patch
-        got_py = py.apply_batch(batch)
-        got_nat = nat.apply_batch(batch)
-        for doc in batch:
-            assert got_py[doc] == expected[doc]
-            assert got_nat[doc] == expected[doc], (
-                'native patch mismatch for doc %r:\nexpected %r\ngot      %r'
-                % (doc, expected[doc], got_nat[doc]))
-
-    for doc in range(n_docs):
-        want = Backend.get_patch(oracle_states[doc])
-        assert nat.get_patch(doc) == want
 
 
 class TestNativeBasics:
@@ -362,9 +335,9 @@ class TestQueryConstLookup:
         patch = pool.get_patch('real')
         assert patch['clock'] == {'a0': 1}
 
-    def test_python_pool(self):
-        pool = TPUDocPool()
-        self._exercise(pool, lambda p: len(p.docs))
+    @pytest.mark.parametrize('pool_lane', ['kernel'], indirect=True)
+    def test_kernel_lane_pool(self, pool_lane):
+        self._exercise(pool_lane(), lambda p: p.doc_count())
 
     def test_native_pool(self):
         from automerge_tpu.native import NativeDocPool
@@ -377,11 +350,12 @@ class TestQueryConstLookup:
         self._exercise(pool, lambda p: sum(s.doc_count()
                                            for s in p.pools))
 
-    def test_unknown_doc_patch_is_empty(self):
-        pool = TPUDocPool()
+    @pytest.mark.parametrize('pool_lane', ['kernel'], indirect=True)
+    def test_unknown_doc_patch_is_empty(self, pool_lane):
+        pool = pool_lane()
         patch = pool.get_patch('ghost')
         assert patch['clock'] == {} and patch['deps'] == {}
-        assert 'ghost' not in pool.docs
+        assert pool.doc_count() == 0
 
 
 class TestBatchHandleLeaks:

@@ -8,13 +8,9 @@ messages heal on later rounds, duplicate deliveries are no-ops.
 
 import random
 
-import pytest
-
 from automerge_tpu.backend import apply_changes as oracle_apply
 from automerge_tpu.backend import get_patch as oracle_get_patch
 from automerge_tpu.backend import init as oracle_init
-from automerge_tpu.native import NativeDocPool
-from automerge_tpu.parallel.engine import TPUDocPool
 from automerge_tpu.sync.replica_set import BatchedReplicaSet, patch_to_tree
 
 ROOT = '00000000-0000-0000-0000-000000000000'
@@ -41,9 +37,8 @@ def partitioned_history(n_replicas, n_docs, rounds=3, seed=5):
     return by_replica, all_changes
 
 
-@pytest.mark.parametrize('pool_factory', [NativeDocPool, TPUDocPool])
-def test_partitioned_backlog_converges(pool_factory):
-    rs = BatchedReplicaSet(4, pool_factory=pool_factory)
+def test_partitioned_backlog_converges(pool_lane):
+    rs = BatchedReplicaSet(4, pool_factory=pool_lane)
     by_replica, all_changes = partitioned_history(4, 3)
     for r, by_doc in enumerate(by_replica):
         rs.apply_batch(r, by_doc)

@@ -10,11 +10,9 @@ import pytest
 
 from automerge_tpu.errors import RangeError
 from automerge_tpu.native import NativeDocPool, ShardedNativePool
-from automerge_tpu.parallel.engine import TPUDocPool
+from tests.conftest import LanePool
 
 ROOT = '00000000-0000-0000-0000-000000000000'
-
-POOLS = [NativeDocPool, TPUDocPool, lambda: ShardedNativePool(n_shards=2)]
 
 
 def build_history(pool, doc='d', seed=3):
@@ -43,15 +41,16 @@ def build_history(pool, doc='d', seed=3):
                           'value': seq}]}])
 
 
-@pytest.mark.parametrize('make_pool', POOLS)
-def test_save_load_round_trip(make_pool):
-    pool = make_pool()
+@pytest.mark.parametrize('pool_lane', ['host', 'kernel', 'sharded'],
+                         indirect=True)
+def test_save_load_round_trip(pool_lane):
+    pool = pool_lane()
     build_history(pool)
     want = pool.get_patch('d')
     blob = pool.save('d')
     assert isinstance(blob, bytes)
 
-    fresh = make_pool()
+    fresh = pool_lane()
     patch = fresh.load('d2', blob)
     assert patch == want
     assert fresh.get_patch('d2') == want
@@ -66,22 +65,21 @@ def test_save_load_round_trip(make_pool):
 
 
 def test_checkpoints_are_cross_pool_compatible():
-    """An engine-pool checkpoint restores into the native pool and vice
-    versa (one wire format)."""
-    tpool = TPUDocPool()
-    build_history(tpool)
-    blob = tpool.save('d')
-    npool = NativeDocPool()
-    assert npool.load('x', blob) == tpool.get_patch('d')
+    """A checkpoint saved on the chip's kernel path restores on the CPU
+    default and vice versa (one wire format)."""
+    kpool = LanePool('kernel')
+    build_history(kpool)
+    blob = kpool.save('d')
+    hpool = LanePool('host')
+    assert hpool.load('x', blob) == kpool.get_patch('d')
 
-    blob2 = npool.save('x')
-    tpool2 = TPUDocPool()
-    assert tpool2.load('y', blob2) == npool.get_patch('x')
+    blob2 = hpool.save('x')
+    kpool2 = LanePool('kernel')
+    assert kpool2.load('y', blob2) == hpool.get_patch('x')
 
 
-@pytest.mark.parametrize('make_pool', [NativeDocPool, TPUDocPool])
-def test_load_rejects_garbage(make_pool):
-    pool = make_pool()
+def test_load_rejects_garbage(pool_lane):
+    pool = pool_lane()
     with pytest.raises(RangeError, match='checkpoint'):
         pool.load('d', b'\x81\xa1x\x01')
 
@@ -107,11 +105,10 @@ def test_load_batch_restores_many_docs_in_one_pass():
         fresh.load_batch({'x': b'garbage'})
 
 
-@pytest.mark.parametrize('make_pool', [NativeDocPool, TPUDocPool])
 @pytest.mark.parametrize('garbage', [b'\x90', b'garbage', b'\xc0'])
-def test_load_rejects_all_malformed_shapes(make_pool, garbage):
+def test_load_rejects_all_malformed_shapes(pool_lane, garbage):
     with pytest.raises(RangeError, match='checkpoint'):
-        make_pool().load('d', garbage)
+        pool_lane().load('d', garbage)
 
 
 def test_sidecar_save_load_survives_json_framing():

@@ -6,7 +6,6 @@ reload-on-touch, the WAL byte bound, and per-connection fan-out frame
 batching."""
 
 import json
-import os
 import random
 import time
 
@@ -15,7 +14,6 @@ import pytest
 
 from automerge_tpu import storage, telemetry
 from automerge_tpu.native import NativeDocPool, ShardedNativePool
-from automerge_tpu.parallel.engine import TPUDocPool
 from automerge_tpu.storage.coldstore import ColdStore, DocEvictor
 from automerge_tpu.storage.columnar import (decode_columnar,
                                             decode_columnar_meta,
@@ -33,26 +31,6 @@ def _reset_metrics():
     telemetry.reset_all()
     yield
     telemetry.reset_all()
-
-
-@pytest.fixture(params=['default', 'kernel'])
-def exec_mode(request):
-    """Both execution modes face the parity lanes: the CPU default
-    (full host path) and the forced kernel path (same pattern as
-    tests/test_chaos.py)."""
-    if request.param == 'kernel':
-        prior = {k: os.environ.get(k)
-                 for k in ('AMTPU_HOST_FULL', 'AMTPU_HOST_REG')}
-        os.environ['AMTPU_HOST_FULL'] = '0'
-        os.environ['AMTPU_HOST_REG'] = '0'
-        yield 'kernel'
-        for k, v in prior.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-    else:
-        yield 'default'
 
 
 def _rand_changes(rng, n_actors=4, n_rounds=6, with_weird=True):
@@ -173,10 +151,8 @@ class TestColumnarCodec(object):
                             'ops': []}, use_bin_type=True)])
         with pytest.raises(RangeError, match='checkpoint'):
             pool.load('d', blob)
-        t = TPUDocPool()
-        from automerge_tpu.errors import RangeError as RE
-        with pytest.raises(RE, match='checkpoint'):
-            t.load('d', blob)
+        with pytest.raises(RangeError, match='checkpoint'):
+            ShardedNativePool(n_shards=2).load('d', blob)
 
     def test_null_deps_or_ops_ride_the_residual_column(self):
         # explicit nulls are legal msgpack but not columnarizable: they
@@ -213,7 +189,7 @@ class TestColumnarCodec(object):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize('fmt', ['columnar', 'json'])
-def test_save_format_oracle_parity(fmt, monkeypatch, exec_mode):
+def test_save_format_oracle_parity(fmt, monkeypatch, pool_lane):
     """Both container formats restore byte-identical state, and the
     decoded changes applied to a fresh pool equal the original apply
     (decode->apply parity oracle, both exec modes)."""
@@ -261,7 +237,7 @@ def _interleaved_history(pool, doc='d'):
 
 
 class TestSettledHistoryGC(object):
-    def test_gc_shrinks_arena_and_straggler_backfills(self, exec_mode):
+    def test_gc_shrinks_arena_and_straggler_backfills(self, pool_lane):
         """A straggler subscriber whose clock sits BEHIND the settled
         frontier still backfills byte-identically via
         get_missing_changes (the GC-frontier lane)."""
@@ -360,7 +336,7 @@ class TestSettledHistoryGC(object):
 @pytest.mark.parametrize('make_pool', [
     NativeDocPool, lambda: ShardedNativePool(n_shards=2)],
     ids=['native', 'sharded'])
-def test_evict_reload_mutate_parity(make_pool, exec_mode):
+def test_evict_reload_mutate_parity(make_pool, pool_lane):
     pool = make_pool()
     twin = make_pool()
     _interleaved_history(pool)
@@ -671,11 +647,16 @@ def test_gc_frontier_from_fanout_keeps_straggler_serveable():
         'storage.snapshot_backfills', 0) == 1
 
 
-def test_engine_pool_checkpoints_stay_cross_compatible():
-    t = TPUDocPool()
-    _interleaved_history(t)
-    n = NativeDocPool()
-    assert n.load('x', t.save('d')) == t.get_patch('d')
-    n.compact('x')
-    t2 = TPUDocPool()
-    assert t2.load('y', n.save('x')) == t.get_patch('d')
+def test_checkpoints_cross_pool_layouts():
+    """A checkpoint crosses pool layouts both ways, compacted history
+    included: what a failover between a multi-chip host's doc-sharded
+    pool and a one-chip host's single pool depends on."""
+    sharded = ShardedNativePool(n_shards=2)
+    _interleaved_history(sharded)
+    want = sharded.get_patch('d')
+    single = NativeDocPool()
+    assert single.load('x', sharded.save('d')) == want
+    assert single.compact('x') > 0
+    back = ShardedNativePool(n_shards=2)
+    assert back.load('y', single.save('x')) == want
+    assert back.get_patch('y') == want

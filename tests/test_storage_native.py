@@ -32,27 +32,6 @@ def _reset():
     telemetry.reset_all()
 
 
-@pytest.fixture(params=['default', 'kernel'])
-def exec_mode(request):
-    """Both execution modes face the parity lanes (same pattern as
-    tests/test_storage.py): arena-direct load always resolves host-
-    side in C++, so its output must match the dict replay under the
-    CPU default AND the forced kernel path."""
-    if request.param == 'kernel':
-        prior = {k: os.environ.get(k)
-                 for k in ('AMTPU_HOST_FULL', 'AMTPU_HOST_REG')}
-        os.environ['AMTPU_HOST_FULL'] = '0'
-        os.environ['AMTPU_HOST_REG'] = '0'
-        yield 'kernel'
-        for k, v in prior.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-    else:
-        yield 'default'
-
-
 def _encode_arm(raws, native):
     """encode_columnar through one dispatch arm (the gate is checked
     per call, so flipping the env interleaves cleanly)."""
@@ -316,7 +295,7 @@ class TestDecodePathParity:
     """Arena-direct native load vs the dict-replay oracle: per-doc
     byte-identical state across both exec modes."""
 
-    def test_load_batch_parity_both_arms(self, exec_mode, monkeypatch):
+    def test_load_batch_parity_both_arms(self, pool_lane, monkeypatch):
         rng = random.Random(11)
         pool = _build_corpus_pool(rng)
         docs = ['doc-%d' % d for d in range(6)]

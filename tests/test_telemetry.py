@@ -298,15 +298,17 @@ def parse_exposition(body):
     return types, samples
 
 
-def _engine_backend():
-    from automerge_tpu.parallel.engine import TPUDocPool
+def _kernel_backend():
+    """A sidecar over a native pool on the chip's kernel path, so the
+    device phases appear in the exposition."""
     from automerge_tpu.sidecar.server import SidecarBackend
-    return SidecarBackend(pool=TPUDocPool())
+    from tests.conftest import LanePool
+    return SidecarBackend(pool=LanePool('kernel'))
 
 
 def test_metrics_request_answers_valid_exposition():
     telemetry.enable()
-    backend = _engine_backend()
+    backend = _kernel_backend()
     resp = backend.handle({'id': 1, 'cmd': 'apply_changes', 'doc': 'd',
                            'changes': [CH]})
     assert 'result' in resp
@@ -322,11 +324,11 @@ def test_metrics_request_answers_valid_exposition():
     by_name = {}
     for name, labels, value in samples:
         by_name.setdefault(name, []).append((labels, value))
-    assert any('pool="engine"' in l for l, _ in
+    assert any('pool="native"' in l for l, _ in
                by_name['amtpu_batch_latency_seconds_bucket'])
     assert float(dict(by_name['amtpu_ops_total'])['']) >= 1
     assert float(dict(by_name['amtpu_docs_total'])['']) >= 1
-    assert any('phase="engine.kernels"' in l for l, _ in
+    assert any('phase="device.dispatch"' in l for l, _ in
                by_name['amtpu_phase_seconds_total'])
     assert any('reason="overflow_batches"' in l for l, _ in
                by_name['amtpu_fallback_total'])
@@ -334,20 +336,20 @@ def test_metrics_request_answers_valid_exposition():
                by_name['amtpu_sidecar_requests_total'])
 
     # histogram invariants: buckets cumulative-monotonic, +Inf == _count
-    eng = [(l, float(v)) for l, v in
+    nat = [(l, float(v)) for l, v in
            by_name['amtpu_batch_latency_seconds_bucket']
-           if 'pool="engine"' in l]
-    counts = [v for _, v in eng]
+           if 'pool="native"' in l]
+    counts = [v for _, v in nat]
     assert counts == sorted(counts)
-    inf = [v for l, v in eng if 'le="+Inf"' in l]
+    inf = [v for l, v in nat if 'le="+Inf"' in l]
     count = [float(v) for l, v in
              by_name['amtpu_batch_latency_seconds_count']
-             if 'pool="engine"' in l]
+             if 'pool="native"' in l]
     assert inf == count and count[0] >= 1
 
 
 def test_healthz_command():
-    backend = _engine_backend()
+    backend = _kernel_backend()
     out = backend.handle({'id': 1, 'cmd': 'healthz'})
     assert out['result']['ok'] is True
     assert 'uptime_s' in out['result']
@@ -411,7 +413,7 @@ def test_sidecar_subprocess_metrics_round_trip():
 
 def test_bench_block_shape():
     telemetry.enable()
-    backend = _engine_backend()
+    backend = _kernel_backend()
     backend.handle({'id': 1, 'cmd': 'apply_changes', 'doc': 'd',
                     'changes': [CH]})
     telemetry.metric('fallback.overflow_batches', 2)
@@ -426,7 +428,7 @@ def test_bench_block_shape():
     # dashboards read explicit zeros before the first gateway request)
     for key in telemetry.KNOWN_SCHEDULER_KEYS:
         assert block['scheduler'][key] == 0, key
-    assert block['batch_latency']['engine']['count'] == 1
+    assert block['batch_latency']['native']['count'] == 1
     assert block['ops_total'] >= 1 and block['docs_total'] >= 1
-    assert 'engine.kernels' in block['phases']
+    assert 'device.dispatch' in block['phases']
     json.dumps(block)    # must be JSON-serializable as-is

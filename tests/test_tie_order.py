@@ -18,8 +18,8 @@ same-actor ties, most-recently-APPLIED op first; across actors, actor id
 descending (identical to the reference).  This file pins:
 
   1. the exact patch our backends emit for the degenerate input,
-  2. that all three backends (scalar oracle, batched Python pool, C++
-     native pool) agree with each other on it, and
+  2. that the scalar oracle and the C++ native pool, on the CPU default
+     and on the chip's kernel path, agree with each other on it, and
   3. that our rule is delivery-order independent even for this input
      (stronger than the reference, whose order is history-dependent).
 
@@ -29,8 +29,7 @@ carried by tests/test_backend.py + tests/test_golden_corpus.py.
 """
 
 from automerge_tpu import backend as Backend
-from automerge_tpu.native import NativeDocPool
-from automerge_tpu.parallel.engine import TPUDocPool
+from tests.conftest import native_lanes
 
 ROOT_ID = '00000000-0000-0000-0000-000000000000'
 
@@ -66,9 +65,10 @@ class TestSameActorDuplicateAssign:
              'conflicts': [{'actor': 'dup', 'value': 1}]}]
 
     def test_three_backends_agree_on_degenerate_input(self):
-        """The deviation is uniform: scalar oracle, batched Python pool,
-        and C++ native pool emit the SAME bytes for duplicate-assign
-        changes (so the deviation cannot cause cross-backend drift)."""
+        """The deviation is uniform: the scalar oracle and the native
+        pool on its `host` and `kernel` lanes emit the SAME bytes for
+        duplicate-assign changes (so the deviation cannot cause
+        cross-backend drift)."""
         changes = [
             _dup_change('alice', 1, [1, 2]),
             _dup_change('bob', 1, [3, 4, 5]),
@@ -76,11 +76,11 @@ class TestSameActorDuplicateAssign:
         ]
         want_final, want_patches = _oracle_patches(changes)
 
-        for pool in (TPUDocPool(), NativeDocPool()):
+        for pool in native_lanes():
             for ch, want in zip(changes, want_patches):
                 got = pool.apply_batch({0: [ch]})[0]
-                assert got == want, type(pool).__name__
-            assert pool.get_patch(0) == want_final, type(pool).__name__
+                assert got == want, pool.lane
+            assert pool.get_patch(0) == want_final, pool.lane
 
     def test_delivery_order_independent(self):
         """Two concurrent degenerate changes produce the same register
