@@ -429,12 +429,31 @@ def _ctx_pending_arrays(ctx):
     combo = ctx.get('combo')
     if combo is not None:
         out.append(combo)
-    elif ctx.get('reg_out') is not None:
-        out.append(ctx['reg_out']['packed'])
+    else:
+        if ctx.get('reg_out') is not None:
+            out.append(ctx['reg_out']['packed'])
+        if ctx.get('rank') is not None:
+            # the non-fused path's list rank: phase a only started its
+            # transfer (`_run_resolver`), phase b reads it
+            out.append(ctx['rank'])
     esc = ctx.get('esc')
     if esc:
         out.extend(t_out['packed'] for _w, _rows, t_out in esc[0])
     return out
+
+
+def _unwind_batch(ctx, exc):
+    """Failure protocol of a begun batch, before its handle is freed:
+    drain every device output still in flight, then roll the pool
+    back.  The drain comes first: those dispatches zero-copied the C++
+    batch columns the free deletes, and would read freed memory; their
+    own errors are moot, the batch is already failing."""
+    for arr in _ctx_pending_arrays(ctx):
+        try:
+            arr.block_until_ready()
+        except Exception:
+            pass
+    _rollback_batch(ctx['bh'], exc)
 
 
 def _run_phase_b_entry(key, pool, ctx, on_result=None, on_error=None):
@@ -448,17 +467,9 @@ def _run_phase_b_entry(key, pool, ctx, on_result=None, on_error=None):
         if on_result is not None:
             on_result(key, result)
     except Exception as e:
-        # drain in-flight kernels BEFORE rollback+free: a phase-b
-        # failure (armed fault, device error) can leave dispatches
-        # that zero-copied the C++ batch columns the free below is
-        # about to delete -- the PR-4 alias class, same drain as
-        # the wave phase-a unwind
-        for arr in _ctx_pending_arrays(ctx):
-            try:
-                arr.block_until_ready()
-            except Exception:
-                pass    # already failing; kernel errors moot
-        _rollback_batch(ctx['bh'], e)
+        # a phase-b failure (armed fault, device error) can leave
+        # dispatches in flight
+        _unwind_batch(ctx, e)
         if on_error is not None:
             on_error(key, e)
         else:
@@ -1206,7 +1217,7 @@ class NativeDocPool:
         try:
             return self._phase_b(ctx)
         except Exception as e:
-            _rollback_batch(ctx['bh'], e)
+            _unwind_batch(ctx, e)
             raise
         finally:
             attribution.note_flush_phase('collect',
@@ -1220,7 +1231,9 @@ class NativeDocPool:
         begin and emit already saturate the core), no armed fault sites
         (chaos lanes pin exact single-batch rollback semantics), and not
         already inside a sharded driver's sub-call (tuple payloads),
-        which pipelines across shards itself."""
+        which pipelines across shards itself.  Both kernel layouts
+        overlap: the fused path's outputs and the non-fused path's list
+        rank are read in phase b, so no phase a blocks on its kernels."""
         if isinstance(payload, tuple):
             return False
         if docs < max(2, _pipeline_min_docs()) or _pipeline_depth() < 2:
@@ -1237,7 +1250,9 @@ class NativeDocPool:
         dispatch runs before any wave blocks on results, and phase b
         drains ready-first (`_collect_ready_order`) -- so wave k+1's
         decode/begin/encode overlaps wave k's in-flight device compute
-        on the SAME NativeDocPool.  Doc-disjointness is what makes the
+        on the SAME NativeDocPool, on the fused and the non-fused
+        (multi-size-class Text) layout alike: neither phase a reads a
+        device output back.  Doc-disjointness is what makes the
         interleaved begins sound: the begin journal, register mirrors,
         member windows, and arenas are all doc-scoped, and the pool-
         global intern/clock tables are append-only.
@@ -1283,17 +1298,11 @@ class NativeDocPool:
                     if i == 0:
                         t_a0 = time.perf_counter()
             except Exception as e:
-                # atomic unwind: reverse begin order, nothing emitted.
-                # Drain each wave's in-flight kernels BEFORE freeing:
-                # their dispatch zero-copied the C++ batch columns the
-                # free is about to delete (the PR-4 alias class).
+                # atomic unwind: reverse begin order, nothing emitted;
+                # each wave's in-flight kernels (its deferred rank
+                # among them) drain before its batch is freed
                 for _i, _p, ctx in reversed(ctxs):
-                    for arr in _ctx_pending_arrays(ctx):
-                        try:
-                            arr.block_until_ready()
-                        except Exception:
-                            pass    # already unwinding; kernel errors moot
-                    _rollback_batch(ctx['bh'], e)
+                    _unwind_batch(ctx, e)
                     _free_batch(ctx['bh'])
                 raise
             if len(ctxs) > 1:
@@ -1510,10 +1519,11 @@ class NativeDocPool:
             # phase-a failure frees its OWN handle (callers only see an
             # exception, never a ctx to free); the live-handle counter
             # stays balanced -- tests assert live_batch_handles() == 0
-            # after forced phase-a errors.  Rollback first: begin already
-            # committed schedule state, and a retry/bisect is only byte-
-            # safe against the pre-begin pool.
-            _rollback_batch(bh, e)
+            # after forced phase-a errors.  Unwind first (drain what was
+            # dispatched, roll back): begin already committed schedule
+            # state, and a retry/bisect is only byte-safe against the
+            # pre-begin pool.
+            _unwind_batch(ctx, e)
             _free_batch(bh)
             raise
 
@@ -1786,10 +1796,7 @@ class NativeDocPool:
                         self._read_register_out(ctx['reg_out'])
                     winner, conflicts, alive, overflow = self._escalate(
                         L, ctx, winner, conflicts, alive, overflow)
-                    rank_arr = (np.ascontiguousarray(
-                        telemetry.d2h_read(ctx['rank']), np.int32)
-                                if ctx['rank'] is not None
-                                else np.zeros(0, np.int32))
+                    rank_arr = self._host_rank(ctx['rank'])
                 hostdom = ctx.get('hostdom')
                 with trace.span('host.mid'):
                     if L.amtpu_mid(bh, ip(winner), ip(conflicts),
@@ -1822,6 +1829,12 @@ class NativeDocPool:
                             _raise_last()
         else:
             reg_out, rank = ctx['reg_out'], ctx['rank']
+            if rank is not None:
+                # the rank's read moved here from phase a: count it, and
+                # whether the resolver had finished before phase b came
+                trace.metric('collect.deferred_rank')
+                if rank.is_ready():
+                    trace.metric('collect.deferred_rank_ready')
             # Packed member epilogue (ISSUE 3 tentpole a): member-mode
             # batches transfer ONE i32 per register row + a sparse CSR
             # conflict gather instead of the full O(Tp x W) matrices;
@@ -1833,7 +1846,7 @@ class NativeDocPool:
                     (packed, conf_rows, conf_offs, conf_vals,
                      residual) = self._collect_member_packed(
                         ctx, reg_out, Tp)
-                    rank_arr = np.ascontiguousarray(rank, np.int32)
+                    rank_arr = self._host_rank(rank)
                 trace.metric('collect.packed_member_batches')
                 with trace.span('host.mid'):
                     if L.amtpu_mid_packed(
@@ -1866,7 +1879,7 @@ class NativeDocPool:
                     else:
                         winner = conflicts = alive = np.zeros(0, np.int32)
                         overflow = np.zeros(0, np.uint8)
-                    rank_arr = np.ascontiguousarray(rank, np.int32)
+                    rank_arr = self._host_rank(rank)
                 with trace.span('host.mid'):
                     if L.amtpu_mid(bh, ip(winner), ip(conflicts),
                                    self._mid_window(ctx, conflicts),
@@ -1906,6 +1919,15 @@ class NativeDocPool:
         ptr = L.amtpu_result(bh, ctypes.byref(out_len))
         return ctypes.string_at(ptr, out_len.value) \
             if out_len.value else b'\x80'
+
+    @staticmethod
+    def _host_rank(rank):
+        """The list rank amtpu_mid takes, np.int32 [Lp], from the device
+        rank a dispatch left in flight (None: the batch has no list
+        work).  Callers sit inside a `device.collect` span."""
+        if rank is None:
+            return np.zeros(0, np.int32)
+        return np.ascontiguousarray(telemetry.d2h_read(rank), np.int32)
 
     @staticmethod
     def _mid_window(ctx, conflicts):
@@ -2139,7 +2161,10 @@ class NativeDocPool:
         """Register resolution + linearization, fused into one dispatch
         when both are needed (halves blocking round trips on the
         high-latency device link).  Returns (reg_out device dict | None,
-        rank np.int32 [Lp])."""
+        rank device array [Lp] | None).  Nothing here blocks: the rank's
+        transfer is started and phase b reads it where amtpu_mid needs
+        it, so a wave's resolver runs on the device while the next
+        wave's C++ begin runs on the host."""
         from ..ops import list_rank, registers as register_ops
         if Tp > 0:
             r = self._register_views(L, bh, Tp, Ap, CTp,
@@ -2159,8 +2184,8 @@ class NativeDocPool:
                 e['obj'], e['par'], e['ctr'], e['act'],
                 e['val'].astype(bool), e['lsi'], n_iters,
                 window=weff, mem_idx=mem)
-            with trace.span('device.collect'):
-                return reg_out, telemetry.d2h_read(rank)
+            rank.copy_to_host_async()
+            return reg_out, rank
         if Tp > 0:
             if mem is not None:
                 reg_out = telemetry.h2d_call(
@@ -2176,15 +2201,15 @@ class NativeDocPool:
                     alive_in=np.ones((Tp,), bool), window=weff,
                     sort_idx=r['si'], clock_table=r['ctab'],
                     clock_idx=r['cidx'])
-            return reg_out, np.zeros((0,), np.int32)
+            return reg_out, None
         if Lp > 0:
             rank = telemetry.h2d_call(list_rank.linearize,
                                       e['obj'], e['par'], e['ctr'], e['act'],
                                       e['val'].astype(bool), n_iters,
                                       sort_idx=e['lsi'])
-            with trace.span('device.collect'):
-                return None, telemetry.d2h_read(rank)
-        return None, np.zeros((0,), np.int32)
+            rank.copy_to_host_async()
+            return None, rank
+        return None, None
 
     def _unpack_register_out(self, reg_out, Tp):
         """One packed [Tp] i32 transfer for winner/alive/overflow plus a
@@ -2333,7 +2358,7 @@ class NativeDocPool:
         try:
             out = self._phase_b(ctx)
         except Exception as e:
-            _rollback_batch(bh, e)
+            _unwind_batch(ctx, e)
             raise
         finally:
             _free_batch(bh)

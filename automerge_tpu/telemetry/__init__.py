@@ -130,10 +130,15 @@ KNOWN_FALLBACK_REASONS = ('layout_batches', 'overflow_batches',
 #                           outputs resolved first
 # wait_in_order          -- rounds where nothing was ready and collect
 #                           blocked on the oldest submission
+# deferred_rank          -- non-fused batches whose list rank was read in
+#                           phase b, not at dispatch
+# deferred_rank_ready    -- of those, ranks already resolved when phase b
+#                           reached them (the overlap's hit count)
 KNOWN_COLLECT_KEYS = ('packed_member_batches', 'full_matrix_readback',
                       'conflict_sparse', 'conflict_dense',
                       'ready_reorder', 'wait_in_order',
-                      'device_merge_chunks', 'overlap_s')
+                      'device_merge_chunks', 'overlap_s',
+                      'deferred_rank', 'deferred_rank_ready')
 
 # pool-resident batch state (ISSUE 6; glossary: docs/OBSERVABILITY.md),
 # pre-seeded so the perf-smoke resident gate reads zeros -- not

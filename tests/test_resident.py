@@ -569,3 +569,280 @@ print('WAVE-PARITY-OK')
                          timeout=300)
     assert out.returncode == 0, out.stdout + out.stderr
     assert 'WAVE-PARITY-OK' in out.stdout
+
+
+# Text docs whose lists fall into several size classes, so a wave's
+# list arenas cannot share one fused kernel layout: every such wave
+# takes the non-fused path (`fallback.layout_batches`), the one whose
+# list rank phase a leaves in flight for phase b to read.
+TEXT_WORKLOAD = r"""
+ROOT = '00000000-0000-0000-0000-000000000000'
+
+def build_text_round(r, docs=24, bad=None):
+    '''Round r of `docs` Text docs: round 1 creates each doc's text with
+    2 to 35 characters; later rounds add two concurrent actors'
+    inserts and a delete.  `bad` names a doc whose change targets an
+    object that does not exist (a begin-time validation error).'''
+    payload = {}
+    for d in range(docs):
+        tid = 'txt%d' % d
+        chs = []
+        if r == 1:
+            ops = [{'action': 'makeText', 'obj': tid},
+                   {'action': 'link', 'obj': ROOT, 'key': 'text',
+                    'value': tid}]
+            prev = '_head'
+            for e in range(1, 3 + (d % 4) * 11):
+                ops.append({'action': 'ins', 'obj': tid, 'key': prev,
+                            'elem': e})
+                ops.append({'action': 'set', 'obj': tid,
+                            'key': 'w0:%d' % e,
+                            'value': chr(97 + (d + e) % 26)})
+                prev = 'w0:%d' % e
+            chs.append({'actor': 'w0', 'seq': 1, 'deps': {}, 'ops': ops})
+        else:
+            for a in (1, 2):
+                ops = []
+                prev = 'w0:1'
+                for k in range(1 + d % 3):
+                    e = 1000 * r + 100 * a + k
+                    ops.append({'action': 'ins', 'obj': tid, 'key': prev,
+                                'elem': e})
+                    ops.append({'action': 'set', 'obj': tid,
+                                'key': 'w%d:%d' % (a, e), 'value': 'x'})
+                    prev = 'w%d:%d' % (a, e)
+                if a == 1 and r == 3:
+                    ops.append({'action': 'del', 'obj': tid,
+                                'key': 'w0:2'})
+                chs.append({'actor': 'w%d' % a, 'seq': r - 1,
+                            'deps': {'w0': 1}, 'ops': ops})
+        if 'doc%d' % d == bad:
+            chs[-1]['ops'].append({'action': 'set', 'obj': 'missing',
+                                   'key': 'k', 'value': 1})
+        payload['doc%d' % d] = chs
+    return payload
+"""
+
+
+def test_wave_pipeline_parity_text_layout_fallback():
+    """The wave pipeline on the non-fused layout path (multi-size-class
+    Text waves): phase a leaves each wave's list rank on the device and
+    phase b reads it, so wave 1's C++ begin runs before wave 0's rank
+    is read.  Every patch stays byte-identical to a clean unpipelined
+    reference and to the oracle, with the resident clock table's
+    delta-upload staging scribbled over the moment its dispatch
+    returns (jax zero-copying a host buffer still in flight)."""
+    script = r"""
+import sys
+sys.path.insert(0, REPO_PATH)
+import os
+import jax; jax.config.update('jax_platforms', 'cpu')
+import numpy as np
+from automerge_tpu import backend as Backend
+from automerge_tpu import telemetry, trace
+from automerge_tpu import native
+from automerge_tpu.native import NativeDocPool
+import automerge_tpu.native.batch_resident as br
+WORKLOAD
+
+def run_rounds():
+    pool = NativeDocPool()
+    for r in (1, 2, 3):
+        pool.apply_batch(build_text_round(r))
+    return [pool.get_patch('doc%d' % i) for i in range(24)]
+
+os.environ['AMTPU_PIPELINE_DEPTH'] = '1'
+os.environ['AMTPU_PIPELINE_MIN_DOCS'] = '4'
+ref = run_rounds()
+states = {}
+for r in (1, 2, 3):
+    for d, chs in build_text_round(r).items():
+        states[d], _ = Backend.apply_changes(states.get(d) or Backend.init(),
+                                             chs)
+assert ref == [Backend.get_patch(states['doc%d' % i]) for i in range(24)], \
+    'unpipelined reference diverged from the oracle'
+
+_orig = br._jit_row_scatter
+def _hostile(donate):
+    fn = _orig(donate)
+    def run(tab, idx, rows):
+        out = fn(tab, idx, rows)
+        idx.fill(127)
+        rows.fill(127)
+        return out
+    return run
+br._jit_row_scatter = _hostile
+
+# call order: each C++ begin, each wave's dispatched rank, each read
+events = []
+L = native.lib()
+_begin = L.amtpu_begin
+def begin(*a):
+    events.append(('begin', None))
+    return _begin(*a)
+L.amtpu_begin = begin
+_read = telemetry.d2h_read
+def d2h_read(x):
+    events.append(('read', id(x)))
+    return _read(x)
+telemetry.d2h_read = d2h_read
+_phase_a = NativeDocPool._phase_a
+def phase_a(self, payload, overlapped=False):
+    ctx = _phase_a(self, payload, overlapped=overlapped)
+    if overlapped and ctx.get('mode') == 'old' and ctx['rank'] is not None:
+        assert not isinstance(ctx['rank'], np.ndarray), 'rank read in phase a'
+        assert hasattr(ctx['rank'], 'is_ready'), type(ctx['rank'])
+        events.append(('wave', id(ctx['rank'])))
+    elif overlapped:
+        events.append(('wave', None))
+    return ctx
+NativeDocPool._phase_a = phase_a
+
+results = {}
+for depth in ('1', '4'):
+    os.environ['AMTPU_PIPELINE_DEPTH'] = depth
+    trace.metrics_reset()
+    results[depth] = run_rounds()
+    m = trace.metrics_snapshot()
+    assert m.get('fallback.layout_batches', 0) > 0, m
+    assert m.get('collect.deferred_rank', 0) > 0, m
+    assert m.get('collect.deferred_rank', 0) >= \
+        m.get('collect.deferred_rank_ready', 0), m
+    if depth == '4':
+        assert m.get('pipeline.waves', 0) > m.get('pipeline.batches', 0), m
+assert results['1'] == ref, 'hostile staging mutation corrupted results'
+assert results['4'] == ref, 'wave path diverged from clean reference'
+
+# in every wave batch whose first wave deferred its rank, the second
+# wave's begin came before that rank was read
+checked = 0
+for i, (kind, rid) in enumerate(events):
+    if kind != 'wave' or rid is None or events[i - 1][0] != 'begin':
+        continue
+    if i >= 2 and events[i - 2][0] == 'wave':
+        continue                          # not the batch's first wave
+    later = events[i + 1:]
+    begins = [j for j, (k, _) in enumerate(later) if k == 'begin']
+    reads = [j for j, (k, x) in enumerate(later) if k == 'read' and x == rid]
+    assert reads, 'deferred rank never read'
+    assert begins and begins[0] < reads[0], \
+        'wave 0 read its rank before wave 1 began'
+    checked += 1
+assert checked > 0, events
+print('TEXT-WAVE-PARITY-OK', checked)
+""".replace('WORKLOAD', TEXT_WORKLOAD).replace('REPO_PATH', repr(REPO))
+    env = dict(os.environ, JAX_PLATFORMS='cpu', AMTPU_HOST_FULL='0',
+               AMTPU_HOST_DOM='0')
+    out = subprocess.run([sys.executable, '-c', script], env=env,
+                         cwd=REPO, capture_output=True, text=True,
+                         timeout=300)
+    assert out.returncode == 0, out.stdout + out.stderr
+    assert 'TEXT-WAVE-PARITY-OK' in out.stdout
+
+
+def test_wave_pipeline_unwind_drains_deferred_rank():
+    """A later wave's begin fails while an earlier Text wave's rank is
+    still deferred: the unwind drains that rank before it frees the
+    wave's batch (the resolver reads zero-copied views of the batch's
+    C++ columns), and the serial replay surfaces the serial error and
+    leaves every doc byte-identical to the serial path."""
+    script = r"""
+import sys
+sys.path.insert(0, REPO_PATH)
+import ctypes
+import os
+import jax; jax.config.update('jax_platforms', 'cpu')
+import msgpack
+from automerge_tpu import native, trace
+from automerge_tpu.errors import AutomergeError
+from automerge_tpu.native import NativeDocPool
+WORKLOAD
+
+os.environ['AMTPU_PIPELINE_MIN_DOCS'] = '4'
+os.environ['AMTPU_PIPELINE_DEPTH'] = '4'
+_phase_a = NativeDocPool._phase_a
+
+# which docs each wave holds (the split hashes doc ids only)
+waves = []
+def record_wave(self, payload, overlapped=False):
+    if overlapped:
+        waves.append(sorted(msgpack.unpackb(
+            ctypes.string_at(*payload), raw=False)))
+    return _phase_a(self, payload, overlapped=overlapped)
+NativeDocPool._phase_a = record_wave
+probe = NativeDocPool()
+probe.apply_batch(build_text_round(1))
+NativeDocPool._phase_a = _phase_a
+assert len(waves) >= 2, waves
+bad = waves[-1][0]
+
+class RankProbe:
+    '''Stands in for a wave's deferred device rank: records its drain.'''
+    def __init__(self, arr, bh):
+        self.arr, self.bh = arr, bh
+    def is_ready(self):
+        return self.arr.is_ready()
+    def block_until_ready(self):
+        events.append(('drain', self.bh))
+        return self.arr.block_until_ready()
+    def __array__(self, dtype=None, copy=None):
+        return self.arr.__array__(dtype)
+
+events = []
+def probe_wave(self, payload, overlapped=False):
+    ctx = _phase_a(self, payload, overlapped=overlapped)
+    if overlapped and ctx.get('mode') == 'old' and ctx['rank'] is not None:
+        ctx['rank'] = RankProbe(ctx['rank'], ctx['bh'])
+        events.append(('deferred', ctx['bh']))
+    return ctx
+_free = native._free_batch
+def free(bh):
+    events.append(('free', bh))
+    return _free(bh)
+
+out = {}
+for depth in ('1', '4'):
+    os.environ['AMTPU_PIPELINE_DEPTH'] = depth
+    NativeDocPool._phase_a = probe_wave
+    native._free_batch = free
+    pool = NativeDocPool()
+    pool.apply_batch(build_text_round(1))
+    del events[:]
+    trace.metrics_reset()
+    try:
+        pool.apply_batch(build_text_round(2, bad=bad))
+        raise SystemExit('payload with a bad doc did not raise')
+    except AutomergeError as e:
+        err = str(e)
+    unwound = list(events)
+    m = trace.metrics_snapshot()
+    NativeDocPool._phase_a = _phase_a
+    native._free_batch = _free
+    pool.apply_batch(build_text_round(2))
+    pool.apply_batch(build_text_round(3))
+    out[depth] = (err, [pool.get_patch('doc%d' % i) for i in range(24)],
+                  unwound, m)
+    assert native.live_batch_handles() == 0
+
+err1, patches1, _, _ = out['1']
+err4, patches4, unwound, m = out['4']
+assert 'missing' in err1, err1
+assert err4 == err1, (err4, err1)
+assert patches4 == patches1, 'wave unwind left other bytes than serial'
+assert m.get('pipeline.serial_replay', 0) >= 1, m
+deferred = [bh for k, bh in unwound if k == 'deferred']
+assert deferred, ('no wave deferred its rank before the failing begin',
+                  unwound)
+for bh in deferred:
+    assert ('drain', bh) in unwound, ('deferred rank never drained', unwound)
+    assert unwound.index(('drain', bh)) < unwound.index(('free', bh)), \
+        'deferred rank drained after its batch was freed'
+print('UNWIND-DRAIN-OK', len(deferred))
+""".replace('WORKLOAD', TEXT_WORKLOAD).replace('REPO_PATH', repr(REPO))
+    env = dict(os.environ, JAX_PLATFORMS='cpu', AMTPU_HOST_FULL='0',
+               AMTPU_HOST_DOM='0', AMTPU_RESILIENCE='0')
+    out = subprocess.run([sys.executable, '-c', script], env=env,
+                         cwd=REPO, capture_output=True, text=True,
+                         timeout=300)
+    assert out.returncode == 0, out.stdout + out.stderr
+    assert 'UNWIND-DRAIN-OK' in out.stdout
